@@ -67,8 +67,6 @@ class ApplyOptions:
     # needs — it decodes arbitrary counts — and what fail_reasons=True
     # API callers keep).
     sweep_mode: str = "bisect"
-    # opt-in jax persistent compilation cache directory (exec_cache)
-    compile_cache_dir: str = ""
     # resume a checkpointed bisection after a crash: sweep-id prefix (or
     # "last") of a journal under <ledger>/checkpoints or
     # SIMON_CHECKPOINT_DIR (resilience/lifecycle.py SweepJournal)
@@ -259,8 +257,6 @@ class Applier:
 
             overrides = weight_overrides_from_file(self.opts.default_scheduler_config)
         self._preemption = not overrides.pop("_disable_preemption", False)
-        if self.opts.compile_cache_dir:
-            overrides.setdefault("compile_cache_dir", self.opts.compile_cache_dir)
         cfg = make_config(snapshot, **overrides)
         lcap = getattr(self, "_ledger_capture", None)
         if lcap is not None:

@@ -113,7 +113,6 @@ def _prepare(entry, apps, opts) -> _Prepared:
     fp_cfg = make_config(snapshot, **{
         k: v for k, v in opts.config_overrides.items()
         if not k.startswith("_")})
-    exec_cache.enable_persistent_cache(cfg.compile_cache_dir)
     # pad on host, transfer NOTHING here: the lane path's only device
     # hop is the stacked fleet batch (a per-cluster transfer would be
     # pulled straight back for stacking — a wasted device round trip)

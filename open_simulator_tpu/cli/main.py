@@ -31,6 +31,11 @@ class _FaultAction(argparse.Action):
         setattr(namespace, self.dest, events)
 
 
+_CACHE_HELP = ("persistent XLA compilation cache directory (default: "
+               "<checkout>/.jax_cache; JAX_COMPILATION_CACHE_DIR, when "
+               "set, wins): repeat runs skip cold compiles")
+
+
 def build_parser() -> argparse.ArgumentParser:
     p = argparse.ArgumentParser(
         prog="simon-tpu",
@@ -60,8 +65,7 @@ def build_parser() -> argparse.ArgumentParser:
              "exhaustive)")
     ap.add_argument(
         "--compile-cache-dir", default="",
-        help="opt-in jax persistent compilation cache directory: repeat "
-             "runs (and restarted servers) skip cold XLA compiles")
+        help=_CACHE_HELP)
     ap.add_argument("--trace-out", default="",
                     help="write a Chrome-trace JSON timeline of this run's "
                          "phases (open in chrome://tracing or Perfetto)")
@@ -138,9 +142,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "simulations (SIMON_WAVES=0 equivalent)")
     sp.add_argument(
         "--compile-cache-dir", default="",
-        help="opt-in jax persistent compilation cache directory: a "
-             "restarted server skips cold XLA compiles for shapes it has "
-             "served before")
+        help=_CACHE_HELP)
     sp.add_argument(
         "--ledger-dir", default="",
         help="run-ledger directory: every simulation this server runs "
@@ -332,8 +334,7 @@ def build_parser() -> argparse.ArgumentParser:
                              "(also honors SIMON_LEDGER_DIR); checkpoints "
                              "live in <ledger>/checkpoints")
     cp_run.add_argument("--compile-cache-dir", default="",
-                        help="opt-in jax persistent compilation cache: "
-                             "repeat campaigns skip cold XLA compiles")
+                        help=_CACHE_HELP)
     cp_run.add_argument("--no-waves", action="store_true",
                         help="disable wave scheduling for every cluster "
                              "(SIMON_WAVES=0 equivalent)")
@@ -413,7 +414,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "(results are bit-identical either way — this "
                          "is a perf/debug switch)")
     rp.add_argument("--compile-cache-dir", default="",
-                    help="opt-in jax persistent compilation cache")
+                    help=_CACHE_HELP)
     rp.add_argument("--ledger-dir", default="",
                     help="run-ledger directory: one RunRecord per "
                          "executed step + a trajectory summary (also "
@@ -583,7 +584,7 @@ def build_parser() -> argparse.ArgumentParser:
                          "round + a summary event (also honors "
                          "SIMON_LEDGER_DIR)")
     tn.add_argument("--compile-cache-dir", default="",
-                    help="opt-in jax persistent compilation cache")
+                    help=_CACHE_HELP)
     tn.add_argument("--no-waves", action="store_true",
                     help="accepted for symmetry: tune rounds run the "
                          "batched scan (no wave plans apply)")
@@ -751,12 +752,6 @@ def _campaign_main(args) -> int:
                 run_campaign,
             )
 
-            if args.compile_cache_dir:
-                from open_simulator_tpu.engine.exec_cache import (
-                    enable_persistent_cache,
-                )
-
-                enable_persistent_cache(args.compile_cache_dir)
             report = run_campaign(CampaignOptions(
                 fleet=args.fleet,
                 apps_dir=args.apps,
@@ -828,12 +823,6 @@ def _replay_main(args) -> int:
 
     from open_simulator_tpu.k8s.loader import load_resources_from_directory
 
-    if args.compile_cache_dir:
-        from open_simulator_tpu.engine.exec_cache import (
-            enable_persistent_cache,
-        )
-
-        enable_persistent_cache(args.compile_cache_dir)
     try:
         with _trace_capture(args.trace_out):
             from open_simulator_tpu.replay import (
@@ -886,12 +875,6 @@ def _tune_main(args) -> int:
 
     from open_simulator_tpu.k8s.loader import load_resources_from_directory
 
-    if args.compile_cache_dir:
-        from open_simulator_tpu.engine.exec_cache import (
-            enable_persistent_cache,
-        )
-
-        enable_persistent_cache(args.compile_cache_dir)
     body = {"mode": args.mode, "variants": args.variants,
             "rounds": args.rounds, "seed": args.seed,
             "elite_frac": args.elite_frac, "sigma": args.sigma,
@@ -1244,6 +1227,11 @@ def _top_main(args) -> int:
         return 0
 
 
+# subcommands that compile and run the engine in this process
+_ENGINE_COMMANDS = frozenset({"apply", "explain", "chaos", "migrate",
+                              "server", "campaign", "replay", "tune"})
+
+
 def main(argv=None) -> int:
     _init_logging()
     parser = build_parser()
@@ -1266,6 +1254,15 @@ def main(argv=None) -> int:
     if args.command == "version":
         print(f"simon-tpu version {__version__}")
         return 0
+
+    if args.command in _ENGINE_COMMANDS:
+        # one persistent compile cache per process, placed before the
+        # first compile (JAX_COMPILATION_CACHE_DIR wins over the flag)
+        from open_simulator_tpu.engine.exec_cache import (
+            enable_persistent_cache,
+        )
+
+        enable_persistent_cache(getattr(args, "compile_cache_dir", ""))
 
     if args.command == "runs":
         return _runs_main(args)
@@ -1370,7 +1367,6 @@ def main(argv=None) -> int:
             extended_resources=[s for s in args.extended_resources.split(",") if s],
             max_new_nodes=args.max_new_nodes,
             sweep_mode=args.sweep_mode,
-            compile_cache_dir=args.compile_cache_dir,
             resume=args.resume,
         )
         try:

@@ -319,7 +319,6 @@ def simulate(
         with span("encode"):
             snapshot = encode_cluster(nodes, pods, encode_options)
         cfg = make_config(snapshot, **config_overrides)
-        exec_cache.enable_persistent_cache(cfg.compile_cache_dir)
         with span("transfer"):
             # bucketed padding: snapshots in the same shape bucket present
             # ONE shape to XLA, so consecutive simulate() calls on slightly

@@ -218,10 +218,9 @@ class SnapshotArrays:
 
 
 # ---- axis metadata ------------------------------------------------------
-# Canonical per-field axis declarations for SnapshotArrays, shared by the
-# consumers that must agree on them: parallel.sweep.shard_arrays (which
-# mesh axis partitions which array) and engine.exec_cache.pad_snapshot_arrays
-# (which axis the shape-bucketing pads). Declared here, next to the
+# Canonical per-field axis declarations for SnapshotArrays, read by
+# engine.exec_cache.pad_snapshot_arrays (which axis the shape-bucketing
+# pads). Declared here, next to the
 # dataclass, so adding a field forces one decision in one place — shape
 # heuristics would misfire whenever P happens to equal N.
 NODE_AXIS_FIRST = frozenset({

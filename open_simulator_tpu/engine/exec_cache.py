@@ -32,9 +32,10 @@ of that:
   Contract: a donated state is DEAD after the call — host anything you
   need from it first (see ARCHITECTURE.md section 9).
 
-* **Persistent compilation cache** (`enable_persistent_cache`): opt-in
-  via `--compile-cache-dir` / `EngineConfig.compile_cache_dir`, wires
-  `jax_compilation_cache_dir` so server restarts skip cold compiles.
+* **Persistent compilation cache** (`enable_persistent_cache`): every
+  entry point places jax's on-disk cache once at start —
+  `JAX_COMPILATION_CACHE_DIR` if set, else `--compile-cache-dir`, else
+  `<checkout>/.jax_cache` — so restarts skip cold compiles.
 
 Telemetry extends the PR 3 jit-cache series instead of inventing names:
 hits/misses/evictions land in `simon_compile_cache_total{fn, event}` and
@@ -580,6 +581,18 @@ def _check_lane_weights(cfg, weights, lanes: int):
     return weights
 
 
+def _home_device(arrs):
+    """The one device a single-device batch runs on: where the snapshot
+    arrays already live (a caller may commit them anywhere, e.g. to the
+    CPU for a reference run beside the chip), else JAX's default."""
+    import jax
+
+    x = arrs.alloc
+    if isinstance(x, jax.Array) and len(x.devices()) == 1:
+        return next(iter(x.devices()))
+    return jax.devices()[0]
+
+
 def run_batched_cached(arrs, masks, cfg, carry=None,
                        fn_name: str = "batched_schedule", waves=None,
                        weights=None, retries: int = 2,
@@ -606,15 +619,19 @@ def run_batched_cached(arrs, masks, cfg, carry=None,
     import jax
     import jax.numpy as jnp
 
-    masks = jnp.asarray(masks)
+    dev = _home_device(arrs)
+    arrs = jax.device_put(arrs, dev)
+    masks = jax.device_put(jnp.asarray(masks), dev)
     lanes = int(masks.shape[0])
     weights = _check_lane_weights(cfg, weights, lanes)
+    if weights is not None:
+        weights = jax.device_put(weights, dev)
     if carry is None:
         carry = _zeros_carry_batch(arrs, cfg, lanes)
+    carry = jax.device_put(carry, dev)
     key = (fn_name, cfg, _shape_sig(arrs), (lanes,) + tuple(masks.shape[1:]),
            str(masks.dtype), waves,
-           None if weights is None else tuple(weights.shape),
-           tuple(str(d) for d in jax.devices()))
+           None if weights is None else tuple(weights.shape), str(dev))
     fn = batched_lane_fn(cfg, waves, weights is not None)
 
     def build():
@@ -637,7 +654,7 @@ def run_batched_cached(arrs, masks, cfg, carry=None,
         compiled = EXEC_CACHE.get_or_compile(key, fn_name, build)
         c = holder.pop("carry", None)
         if c is None:
-            c = _zeros_carry_batch(arrs, cfg, lanes)
+            c = jax.device_put(_zeros_carry_batch(arrs, cfg, lanes), dev)
         out = (compiled(arrs, masks, c) if weights is None
                else compiled(arrs, masks, c, weights))
         # block INSIDE the fault domain: dispatch is async, so a real
@@ -661,35 +678,45 @@ def run_batched_cached(arrs, masks, cfg, carry=None,
         live.DEVMEM.release(live.OWNER_CARRIES, carry_key)
 
 
-def _mesh_input_shardings(arrs, mesh):
-    """Per-field NamedShardings for a SnapshotArrays under the GSPMD mesh.
+def refuse_node_axis(n_node: int) -> None:
+    """Meshes split lanes over "scenario" only. A "node" axis above 1 is
+    refused: splitting the node-major snapshot fields over it placed
+    ~51,150 of 51,200 pods per lane differently from one chip on a 2x2
+    v5e mesh (chip_smoke.py --mesh, PR 21) while XLA:CPU matched. With
+    the one-hot broadcasts turned into domain-id gathers the same split
+    matched chip 0 in a diagnostic run, but the product path has not
+    been run on four chips since — ROADMAP B3."""
+    if n_node > 1:
+        raise ValueError(
+            f"a 'node' mesh axis of {n_node} is refused until the "
+            "node-split program is verified on TPU against one chip "
+            "(ROADMAP B3); use n_node=1 and split lanes over 'scenario'")
 
-    The per-node resource state — the NODE_AXIS_FIRST fields: alloc,
-    gpu_slot, vg_cap, ... — splits across the "node" mesh axis; that is
-    the state that actually scales with cluster size. The class-table
-    fields whose node axis comes SECOND (topo_onehot, has_key,
-    class_*) replicate: their leading axis is a vocab of
-    constraint/topology classes read by dynamic domain gathers inside
-    the scan (`state.dom_count[k1i, :, g]` and friends), and the SPMD
-    partitioner cannot split those gathers — a "node" split there fails
-    HLO verification after partitioning ("slice dim size greater than
-    dynamic slice dimension"). They are vocab x N tables, small next to
-    the [N, R] state, so replication costs little HBM. Pod-axis and
-    vocab fields replicate too (every lane reads all pods). Returned as
-    a SnapshotArrays of shardings — the registered pytree doubles as
-    the in_shardings tree."""
+
+def mesh_shardings(arrs, carry, mesh):
+    """((arrs, masks, carry, weights) in-shardings, out-shardings) of the
+    mesh executable: lanes split over "scenario", the snapshot arrays
+    replicated on every chip (`refuse_node_axis` says why there is no
+    node split)."""
+    import jax
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
 
-    def spec_for(name: str, x) -> NamedSharding:
-        nd = np.asarray(x).ndim
-        if name in NODE_AXIS_FIRST:
-            return NamedSharding(mesh, P("node", *([None] * (nd - 1))))
-        return NamedSharding(mesh, P())
+    from open_simulator_tpu.engine.scheduler import ScheduleOutput
 
-    out = {f.name: spec_for(f.name, getattr(arrs, f.name))
-           for f in dataclasses.fields(arrs)}
-    return type(arrs)(**out)
+    refuse_node_axis(int(dict(mesh.shape).get("node", 1)))
+    replicated = NamedSharding(mesh, P())
+    lane_sh = NamedSharding(mesh, P("scenario"))
+    rows_sh = NamedSharding(mesh, P("scenario", None))
+    carry_sh = jax.tree_util.tree_map(lambda _: lane_sh, carry)
+    # every output follows the lane axis, the state included — matching
+    # the donated carry's in_sharding so donation aliases shard-for-shard
+    out_sh = ScheduleOutput(
+        node=lane_sh, fail_counts=lane_sh, feasible=lane_sh,
+        gpu_pick=lane_sh, vol_pick=lane_sh, topk_node=lane_sh,
+        topk_score=lane_sh, topk_parts=lane_sh, state=carry_sh)
+    return ((jax.tree_util.tree_map(lambda _: replicated, arrs), rows_sh,
+             carry_sh, rows_sh), out_sh)
 
 
 def run_mesh_cached(arrs, masks, cfg, mesh, carry=None,
@@ -698,8 +725,8 @@ def run_mesh_cached(arrs, masks, cfg, mesh, carry=None,
                     backoff_s: float = 0.05):
     """`run_batched_cached` under a GSPMD mesh: the SAME module-level
     lane-fn, AOT-compiled with in/out shardings — scenario lanes split
-    across the "scenario" mesh axis, node-major snapshot fields across
-    the "node" axis — and cached under the single-device key EXTENDED by
+    across the "scenario" mesh axis, the snapshot replicated
+    (`mesh_shardings`) — and cached under the single-device key EXTENDED by
     the mesh axis split. Same-bucket mesh launches are zero recompiles
     (`simon_compile_cache_total{fn=mesh_schedule}`), and because the
     traced program is identical to the single-device path's, outputs are
@@ -718,8 +745,6 @@ def run_mesh_cached(arrs, masks, cfg, mesh, carry=None,
     straight in."""
     import jax
     import jax.numpy as jnp
-    from jax.sharding import NamedSharding
-    from jax.sharding import PartitionSpec as P
 
     masks = jnp.asarray(masks)
     lanes = int(masks.shape[0])
@@ -737,32 +762,18 @@ def run_mesh_cached(arrs, masks, cfg, mesh, carry=None,
            axis_split, tuple(str(d) for d in mesh.devices.flat))
     fn = batched_lane_fn(cfg, waves, weights is not None)
 
-    lane_sh = NamedSharding(mesh, P("scenario"))
-    arrs_sh = _mesh_input_shardings(arrs, mesh)
-    mask_sh = NamedSharding(mesh, P("scenario", None))
-    carry_sh = jax.tree_util.tree_map(lambda _: lane_sh, carry)
-    w_sh = NamedSharding(mesh, P("scenario", None))
+    (arrs_sh, mask_sh, carry_sh, w_sh), out_sh = mesh_shardings(
+        arrs, carry, mesh)
     # place every input against its declared sharding BEFORE lowering —
     # a no-op for data already resident there (the donated state from
-    # the previous round), a resharding copy for host arrays and for
-    # arrays placed differently (e.g. parallel.sweep.shard_arrays'
-    # HBM-distribution layout); pjit rejects committed args whose
-    # sharding disagrees with in_shardings, so placement cannot be
-    # deferred to launch time
+    # the previous round), a copy for host arrays and arrays placed
+    # elsewhere; pjit rejects committed args whose sharding disagrees
+    # with in_shardings, so placement cannot be deferred to launch time
     arrs = jax.device_put(arrs, arrs_sh)
     masks = jax.device_put(masks, mask_sh)
     carry = jax.device_put(carry, carry_sh)
     if weights is not None:
         weights = jax.device_put(weights, w_sh)
-    # every output follows the lane axis, the state included — matching
-    # the donated carry's in_sharding so donation aliases shard-for-shard
-    from open_simulator_tpu.engine.scheduler import ScheduleOutput
-
-    out_sh = ScheduleOutput(
-        node=lane_sh, fail_counts=lane_sh, feasible=lane_sh,
-        gpu_pick=lane_sh, vol_pick=lane_sh, topk_node=lane_sh,
-        topk_score=lane_sh, topk_parts=lane_sh, state=carry_sh)
-
     def build():
         if weights is None:
             return jax.jit(
@@ -903,16 +914,28 @@ def run_fleet_batched(arrs_batch, masks, cfg,
 
 # ---- persistent compilation cache --------------------------------------
 
+CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+# the cache's home when neither the environment nor a flag names one: a
+# fixed path inside the checkout (never a temp name, pid or time — a
+# directory that moves is a cache that never hits)
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".jax_cache")
+
 _persistent_dir: Optional[str] = None
 
 
-def enable_persistent_cache(path: str) -> None:
-    """Opt into jax's on-disk compilation cache so process restarts skip
-    cold compiles (the `--compile-cache-dir` CLI flag and
-    `EngineConfig.compile_cache_dir` both land here). Idempotent."""
+def enable_persistent_cache(path: str = "") -> str:
+    """Place jax's on-disk compilation cache; every entry point (CLI
+    ``main``, the server, ``bench.py``, ``chip_smoke.py``) calls this
+    once, before its first compile. ``JAX_COMPILATION_CACHE_DIR``, when
+    set, IS the cache and no flag overrides it; otherwise ``path`` (the
+    ``--compile-cache-dir`` flag), else ``DEFAULT_CACHE_DIR``. Returns
+    the directory in effect. Idempotent."""
     global _persistent_dir
-    if not path or _persistent_dir == path:
-        return
+    path = os.environ.get(CACHE_ENV) or path or DEFAULT_CACHE_DIR
+    if _persistent_dir == path:
+        return path
     import jax
 
     os.makedirs(path, exist_ok=True)
@@ -933,4 +956,5 @@ def enable_persistent_cache(path: str) -> None:
         _log.warning("could not reset jax's compilation-cache state; the "
                      "persistent cache may stay cold this process")
     _persistent_dir = path
-    _log.info("persistent compilation cache enabled at %s", path)
+    _log.info("persistent compilation cache at %s", path)
+    return path

@@ -29,6 +29,8 @@ from open_simulator_tpu.encode.snapshot import (
     SnapshotArrays,
 )
 from open_simulator_tpu.ops import filters, gpu_share, scores, storage
+from open_simulator_tpu.ops.exact import div, mul
+from open_simulator_tpu.ops.domains import broadcast_domains, hoist_active_stats
 
 # The K score-plugin weights, in the order the traced-weight vector
 # (EngineConfig.traced_weights) threads them through the step — the
@@ -163,12 +165,6 @@ class EngineConfig(NamedTuple):
     # carry no gpu/storage/WFC-volume claims (those picks are
     # order-dependent within the prefix) and no extensions are registered.
     forced_prefix: int = 0
-    # Opt-in on-disk XLA compilation cache (engine/exec_cache.py
-    # enable_persistent_cache): when non-empty, the simulate/sweep entry
-    # points point jax_compilation_cache_dir here so a restarted server
-    # or a re-run CLI skips cold compiles. Not read inside the trace —
-    # it configures the jax runtime, once, on the host.
-    compile_cache_dir: str = ""
     # Wave scheduling (engine/waves.py): entry points partition the pod
     # sequence into carry-independent waves and hand schedule_pods a
     # static WavePlan; provably-independent runs execute as one batched
@@ -750,6 +746,9 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
     # is only ever multiplied, never branched on)
     n_nodes = arrs.alloc.shape[0]
     f32 = jnp.float32
+    # every matmul below sums exact integer counts in f32; the TPU's
+    # default precision rounds f32 operands to bf16, exact only to 256
+    hp = jax.lax.Precision.HIGHEST
     true_v = jnp.ones((n_nodes,), dtype=bool)  # identity-compared below
 
     # compact carry columns are stored bf16; columns are cast to f32 AT THE
@@ -799,9 +798,9 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
         back = None
         for kk in range(k1s):
             ohk = arrs.topo_onehot[kk]                           # [N, D]
-            pdk = ohk.T @ colsf                                  # [D, Q]
+            pdk = jnp.matmul(ohk.T, colsf, precision=hp)        # [D, Q]
             pd_list.append(pdk)
-            bk = ohk @ pdk                                       # [N, Q]
+            bk = broadcast_domains(pdk, hoisted.dom_idx[kk])     # [N, Q]
             if k1s == 1:
                 back = bk
             else:
@@ -931,7 +930,8 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
                     soft = x["spread_valid"][c] & ~x["spread_hard"][c]
                     w = hoisted.log_dom[skey[c]]
                     spread_raw += jnp.where(
-                        soft, dc_s[:, c] * w + (x["spread_skew"][c] - 1.0), 0.0)
+                        soft, mul(dc_s[:, c], w) + (x["spread_skew"][c] - 1.0),
+                        0.0)
                     spread_node_ok &= ~soft | nh_s[c]
                     any_soft |= soft
     elif cfg.enable_spread:
@@ -946,11 +946,11 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
             k1i = jnp.maximum(kid - 1, 0)
             if k1_static == 1:  # single non-hostname key: no dynamic gather
                 dcol = state.dom_count[0, :, g]        # [D]
-                oh = arrs.topo_onehot[0]               # [N, D]
+                didx = hoisted.dom_idx[0]              # [N]
             else:
                 dcol = state.dom_count[k1i, :, g]
-                oh = arrs.topo_onehot[k1i]
-            dc = oh @ dcol                     # broadcast, no N-reduction
+                didx = hoisted.dom_idx[k1i]
+            dc = broadcast_domains(dcol, didx)         # no N-reduction
             node_has = arrs.has_key[kid] > 0
             if cfg.enable_spread_hard:
                 # hard constraint (DoNotSchedule) -> filter; minMatchNum
@@ -973,7 +973,8 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
             if cfg.enable_spread_soft:
                 soft = x["spread_valid"][c] & ~x["spread_hard"][c]
                 w = hoisted.log_dom[kid]
-                spread_raw += jnp.where(soft, dc * w + (x["spread_skew"][c] - 1.0), 0.0)
+                spread_raw += jnp.where(
+                    soft, mul(dc, w) + (x["spread_skew"][c] - 1.0), 0.0)
                 spread_node_ok &= ~soft | node_has
                 any_soft |= soft
     else:
@@ -1145,7 +1146,8 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
         # existing pods' preferred (anti-)affinity toward this pod: one
         # mat-vec against the weighted domain paint (interpodaffinity/
         # scoring.go's "existing pod" direction)
-        existing_pref_raw = state.pref_paint @ x["hit_pref"].astype(f32)
+        existing_pref_raw = jnp.matmul(
+            state.pref_paint, x["hit_pref"].astype(f32), precision=hp)
         raw_ip = scores.interpod_preference_raw(
             gc, arrs.topo_onehot, arrs.has_key,
             x["pref_group"], x["pref_key"], x["pref_weight"], x["pref_valid"],
@@ -1193,32 +1195,35 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
             (0,),
         )
 
+    # weights multiply through exact.mul: a traced (or non-power-of-two)
+    # weight makes an inexact product, and the CPU would fuse it into +=
     if use_na:
-        score += _part(w_na * scores.max_apply(raw_na, -reds[i_na]))
+        score += _part(mul(w_na, scores.max_apply(raw_na, -reds[i_na])))
     if use_tt:
-        score += _part(
-            w_tt * scores.max_apply(raw_tt, -reds[i_tt], reverse=True))
+        score += _part(mul(
+            w_tt, scores.max_apply(raw_tt, -reds[i_tt], reverse=True)))
     if use_ip:
-        score += _part(w_ip * scores.minmax_apply(
-            raw_ip, reds[i_ip_lo], -reds[i_ip_hi]))
+        score += _part(mul(w_ip, scores.minmax_apply(
+            raw_ip, reds[i_ip_lo], -reds[i_ip_hi])))
     if use_sp:
-        score += _part(w_sp * scores.spread_apply(
-            spread_raw, reds[i_sp_lo], -reds[i_sp_hi], spread_node_ok, any_soft))
+        score += _part(mul(w_sp, scores.spread_apply(
+            spread_raw, reds[i_sp_lo], -reds[i_sp_hi], spread_node_ok,
+            any_soft)))
     if use_si:
-        score += _part(w_si * scores.minmax_apply(
-            raw_si, reds[i_si_lo], -reds[i_si_hi]))
+        score += _part(mul(w_si, scores.minmax_apply(
+            raw_si, reds[i_si_lo], -reds[i_si_hi])))
     if cfg.enable_gpu:
         # cnt==0 pods score 0 on the GPU dimension (scalar factor)
-        score += _part((w_gp * (x["gpu_cnt"] > 0)) * scores.minmax_apply(
-            raw_gp, reds[i_gp_lo], -reds[i_gp_hi]))
+        score += _part(mul(w_gp * (x["gpu_cnt"] > 0), scores.minmax_apply(
+            raw_gp, reds[i_gp_lo], -reds[i_gp_hi])))
     for ext, raw_e, lo_i, hi_i in ext_scores:
         if lo_i is not None:
-            score += _part(
-                ext.weight * scores.minmax_apply(raw_e, reds[lo_i], -reds[hi_i]))
+            score += _part(mul(ext.weight, scores.minmax_apply(
+                raw_e, reds[lo_i], -reds[hi_i])))
         elif hi_i is not None:
-            score += _part(ext.weight * scores.max_apply(raw_e, -reds[hi_i]))
+            score += _part(mul(ext.weight, scores.max_apply(raw_e, -reds[hi_i])))
         else:
-            score += _part(ext.weight * raw_e)
+            score += _part(mul(ext.weight, raw_e))
 
     # Preemption retry: a nominated node (status.nominatedNodeName analog,
     # defaultpreemption PostFilter) restricts the pick to that node while it
@@ -1352,10 +1357,13 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
     # feeding the anti-affinity term paint and the preferred-term paint
     if cfg.enable_anti_affinity or cfg.enable_pref:
         k1 = arrs.topo_onehot.shape[0]
+        d_end = arrs.topo_onehot.shape[2]  # dom_idx of a node without the key
         sd_list = [onehot_n]  # hostname
         for kk in range(k1):
-            oh = arrs.topo_onehot[kk]
-            sd_list.append(oh @ oh[safe_node] * bound.astype(f32))
+            didx = hoisted.dom_idx[kk]                # [N]
+            mine = didx[safe_node]
+            sd_list.append(((didx == mine) & (mine < d_end) & bound)
+                           .astype(f32))
         sd_all = jnp.stack(sd_list)                   # [K, N]
 
     if cfg.enable_anti_affinity:
@@ -1574,16 +1582,13 @@ def schedule_pods(
         xs["_nominated"] = nominated.astype(jnp.int32)
     elif live is None:
         xs["_nominated"] = jnp.full(n_scan, -1, jnp.int32)
-    if cfg.enable_spread:
-        from open_simulator_tpu.ops.domains import hoist_active_stats
-
-        hoisted = hoist_active_stats(
-            arrs.topo_onehot, arrs.has_key, arrs.class_affinity, active)
-    else:
-        hoisted = None
+    # built whatever the config: fields no enabled op reads are dead code
+    hoisted = hoist_active_stats(
+        arrs.topo_onehot, arrs.has_key, arrs.class_affinity, active)
     # loop-invariant reciprocal: the per-step resource-score divides become
     # multiplies (inv = 0 encodes the cap<=0 -> fraction 0 convention)
-    inv_alloc = jnp.where(arrs.alloc > 0, 1.0 / jnp.where(arrs.alloc > 0, arrs.alloc, 1.0), 0.0)
+    inv_alloc = jnp.where(arrs.alloc > 0,
+                          div(1.0, jnp.where(arrs.alloc > 0, arrs.alloc, 1.0)), 0.0)
     if live is not None:
         xs = {k: v for k, v in xs.items() if k in live}
     gcr_seg = _gcr_segments(cfg, scan_arrs)
@@ -1761,6 +1766,15 @@ def make_config(snapshot: ClusterSnapshot, **overrides) -> EngineConfig:
             # volumes already sit on the node) — exact only pod-by-pod
             fp = 0
     kw["forced_prefix"] = fp
+    if "compile_cache_dir" in overrides:
+        from open_simulator_tpu.errors import SimulationError
+
+        raise SimulationError(
+            "the compile_cache_dir engine override was removed in PR 21",
+            code="E_SPEC", field="config_overrides.compile_cache_dir",
+            hint="place the cache once per process with "
+                 "engine.exec_cache.enable_persistent_cache(path), or set "
+                 "JAX_COMPILATION_CACHE_DIR")
     kw.update(overrides)
     if kw.get("extensions"):
         kw["extensions"] = tuple(e.validate() for e in kw["extensions"])

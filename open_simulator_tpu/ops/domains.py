@@ -20,6 +20,8 @@ from typing import NamedTuple
 
 import jax.numpy as jnp
 
+from open_simulator_tpu.ops.exact import log_table, mm
+
 
 def _onehot_for_key(topo_onehot: jnp.ndarray, key_id) -> jnp.ndarray:
     """Gather the [N, D] one-hot matrix for a (traced) key id >= 1."""
@@ -30,7 +32,7 @@ def _onehot_for_key(topo_onehot: jnp.ndarray, key_id) -> jnp.ndarray:
 def domain_count(count_vec: jnp.ndarray, key_id, topo_onehot: jnp.ndarray) -> jnp.ndarray:
     """[N] -> [N]: for each node, the sum of count_vec over its topology domain."""
     oh = _onehot_for_key(topo_onehot, key_id)
-    per_node = oh @ (oh.T @ count_vec)
+    per_node = mm(oh, mm(oh.T, count_vec))
     return jnp.where(key_id == 0, count_vec, per_node)
 
 
@@ -43,8 +45,8 @@ def domain_min(count_vec: jnp.ndarray, key_id, topo_onehot: jnp.ndarray, eligibl
     big = jnp.float32(3.4e38)
     oh = _onehot_for_key(topo_onehot, key_id)
     elig_f = eligible.astype(count_vec.dtype)
-    per_domain = oh.T @ count_vec                     # [D]
-    domain_has = (oh.T @ elig_f) > 0                  # [D]
+    per_domain = mm(oh.T, count_vec)                  # [D]
+    domain_has = mm(oh.T, elig_f) > 0                 # [D]
     min_other = jnp.min(jnp.where(domain_has, per_domain, big))
     # hostname: every node is a domain; min over eligible nodes directly
     min_host = jnp.min(jnp.where(eligible, count_vec, big))
@@ -67,6 +69,9 @@ class ActiveHoist(NamedTuple):
     elig_host: jnp.ndarray    # [C, N] bool: active & class-affinity (hostname elig)
     domain_has: jnp.ndarray   # [C, K1, D] bool: domain holds an eligible node
     any_elig: jnp.ndarray     # [C, K] bool: any eligible node exists under key
+    dom_idx: jnp.ndarray      # [K1, N] i32: the node's domain per key, D if
+                              # it lacks the key — a one-hot broadcast
+                              # `O @ v` is the exact gather `v[dom_idx]`
 
 
 def hoist_active_stats(
@@ -86,16 +91,35 @@ def hoist_active_stats(
     # per-class spread eligibility: active & class node-affinity & has-key
     elig_ck = class_affinity[:, None, :] & active[None, None, :] & (has_key[None, :, :] > 0)  # [C, K, N]
     domain_has = jnp.stack([
-        (elig_ck[:, k + 1, :].astype(f32) @ topo_onehot[k]) > 0 for k in range(k1)
+        mm(elig_ck[:, k + 1, :].astype(f32), topo_onehot[k]) > 0 for k in range(k1)
     ], axis=1) if k1 else jnp.zeros((class_affinity.shape[0], 0, 0), bool)   # [C, K1, D]
     stacked = jnp.stack(dom_counts)
     return ActiveHoist(
         dom_counts=stacked,
-        log_dom=jnp.log(stacked + 2.0),
+        # counts are exact integers <= N, so log(count + 2) is a lookup
+        log_dom=log_table(active.shape[0] + 3)[
+            stacked.astype(jnp.int32) + 2],
         elig_host=elig_ck[:, 0, :],
         domain_has=domain_has,
         any_elig=jnp.any(elig_ck, axis=2),
+        dom_idx=domain_index(topo_onehot),
     )
+
+
+def domain_index(topo_onehot: jnp.ndarray) -> jnp.ndarray:
+    """[K1, N] i32 domain id of every node under every non-hostname key;
+    D (one past the last domain) where the node lacks the key."""
+    d = topo_onehot.shape[2]
+    ids = jnp.max(jnp.where(topo_onehot > 0, jnp.arange(d, dtype=jnp.int32),
+                            -1), axis=2)
+    return jnp.where(ids < 0, d, ids)
+
+
+def broadcast_domains(per_domain: jnp.ndarray, dom_idx: jnp.ndarray) -> jnp.ndarray:
+    """[D, ...] per-domain values -> [N, ...] per node by domain id, 0
+    where the node lacks the key: `O @ per_domain` as a gather, exact on
+    every backend with no matmul."""
+    return jnp.take(per_domain, dom_idx, axis=0, mode="fill", fill_value=0)
 
 
 def domain_min_hoisted(
@@ -106,7 +130,7 @@ def domain_min_hoisted(
     eligibility mat-vec per constraint per step."""
     big = jnp.float32(3.4e38)
     oh = _onehot_for_key(topo_onehot, key_id)
-    per_domain = oh.T @ count_vec                     # [D]
+    per_domain = mm(oh.T, count_vec)                  # [D]
     dhas = h.domain_has[class_id, jnp.maximum(key_id - 1, 0)]
     min_other = jnp.min(jnp.where(dhas, per_domain, big))
     min_host = jnp.min(jnp.where(h.elig_host[class_id], count_vec, big))
@@ -119,6 +143,6 @@ def same_domain(node_id, key_id, topo_onehot: jnp.ndarray, n_nodes: int) -> jnp.
     (used to paint anti-affinity term blocks across a domain on bind)."""
     oh = _onehot_for_key(topo_onehot, key_id)
     dom_row = oh[node_id]                             # [D]
-    same = oh @ dom_row                               # [N]
+    same = mm(oh, dom_row)                            # [N]
     host = jnp.zeros((n_nodes,), dtype=topo_onehot.dtype).at[node_id].set(1.0)
     return jnp.where(key_id == 0, host, same)

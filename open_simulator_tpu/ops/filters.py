@@ -10,6 +10,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from open_simulator_tpu.ops.domains import domain_count, domain_min
+from open_simulator_tpu.ops.exact import mm
 
 
 def fit_per_resource(headroom: jnp.ndarray, req_p: jnp.ndarray) -> jnp.ndarray:
@@ -84,7 +85,7 @@ def anti_blocked_dense(term_block: jnp.ndarray, hit_terms_p: jnp.ndarray) -> jnp
     """Reverse anti-affinity verdict, dense form: sum the paint over every
     term whose selector matches this pod (sum of nonnegative counts > 0
     cannot false-positive in bf16)."""
-    return (term_block @ hit_terms_p.astype(term_block.dtype)) > 0
+    return mm(term_block, hit_terms_p.astype(term_block.dtype)) > 0
 
 
 # NOTE: the standalone topology_spread_ok op was removed in round 4: the

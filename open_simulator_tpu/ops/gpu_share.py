@@ -30,6 +30,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from open_simulator_tpu.ops.exact import div, mul
+
 _BIG = jnp.float32(3.4e38)
 
 
@@ -40,7 +42,7 @@ def _slots_per_device(
     single physical device can hold (the two-pointer inner loop)."""
     free = gpu_cap - gpu_used
     mem_safe = jnp.where(mem_p > 0, mem_p, 1.0)
-    slots = jnp.floor(jnp.clip(free, 0.0) / mem_safe)
+    slots = jnp.floor(div(jnp.clip(free, 0.0), mem_safe))
     return jnp.where(gpu_slot > 0, slots, 0.0)
 
 
@@ -107,8 +109,9 @@ def gpu_share_raw(
     free_total = jnp.sum(jnp.where(gpu_slot > 0, gpu_cap[:, None] - gpu_used, 0.0), axis=1)
     want = mem_p * cnt_p
     avail = free_total - want
-    share = jnp.where(avail > 0, want / jnp.where(avail > 0, avail, 1.0), jnp.where(want > 0, 1.0, 0.0))
-    return jnp.clip(share, 0.0, 1.0) * 100.0
+    share = jnp.where(avail > 0, div(want, jnp.where(avail > 0, avail, 1.0)),
+                      jnp.where(want > 0, 1.0, 0.0))
+    return mul(jnp.clip(share, 0.0, 1.0), 100.0)
 
 
 def gpu_pick_devices(

@@ -11,6 +11,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from open_simulator_tpu.ops.domains import domain_count
+from open_simulator_tpu.ops.exact import div, mul
 
 MAX_SCORE = jnp.float32(100.0)
 _EPS = jnp.float32(1e-9)
@@ -112,26 +113,23 @@ def resource_scores_fused(
     exact ``+0.0`` (the terms are finite and nonnegative), so the traced
     path at the constant path's weight values is bit-identical to it."""
     ci, mi = cpu_mem_idx
-    h_c = (headroom[:, ci] - req_p[ci]) * inv_alloc[:, ci]
-    h_m = (headroom[:, mi] - req_p[mi]) * inv_alloc[:, mi]
+    h_c = mul(headroom[:, ci] - req_p[ci], inv_alloc[:, ci])
+    h_m = mul(headroom[:, mi] - req_p[mi], inv_alloc[:, mi])
     out = jnp.zeros(headroom.shape[:1], dtype=jnp.float32)
     if always_on or w_balanced:
-        out = out + w_balanced * ((1.0 - jnp.abs(h_c - h_m) * 0.5) * MAX_SCORE)
+        out = out + mul(w_balanced, mul(1.0 - jnp.abs(h_c - h_m) * 0.5,
+                                        MAX_SCORE))
     if always_on or w_least:
-        out = out + w_least * (
-            (jnp.maximum(h_c, 0.0) + jnp.maximum(h_m, 0.0)) * (MAX_SCORE / 2.0)
-        )
+        out = out + mul(w_least, mul(
+            jnp.maximum(h_c, 0.0) + jnp.maximum(h_m, 0.0), MAX_SCORE / 2.0))
     if always_on or w_most:
         # mostRequestedScore returns 0 when capacity == 0
         # (most_allocated.go:49-51): h is 0 there (inv_alloc == 0), which
         # would read as "fully used" = full score — mask those resources out
-        out = out + w_most * (
-            (
-                jnp.clip(1.0 - h_c, 0.0, 1.0) * (inv_alloc[:, ci] > 0)
-                + jnp.clip(1.0 - h_m, 0.0, 1.0) * (inv_alloc[:, mi] > 0)
-            )
-            * (MAX_SCORE / 2.0)
-        )
+        out = out + mul(w_most, mul(
+            jnp.clip(1.0 - h_c, 0.0, 1.0) * (inv_alloc[:, ci] > 0)
+            + jnp.clip(1.0 - h_m, 0.0, 1.0) * (inv_alloc[:, mi] > 0),
+            MAX_SCORE / 2.0))
     return out
 
 
@@ -146,11 +144,11 @@ def simon_max_share_raw(alloc: jnp.ndarray, req_p: jnp.ndarray) -> jnp.ndarray:
     requested = jnp.broadcast_to(req_p[None, :], alloc.shape)
     share = jnp.where(
         avail != 0,
-        requested / jnp.where(avail != 0, avail, 1.0),
+        div(requested, jnp.where(avail != 0, avail, 1.0)),
         jnp.where(requested != 0, 1.0, 0.0),
     )
     share = jnp.where(requested > 0, jnp.clip(share, 0.0, 1.0), 0.0)
-    return jnp.max(share, axis=1) * MAX_SCORE
+    return mul(jnp.max(share, axis=1), MAX_SCORE)
 
 
 def simon_max_share_score(alloc: jnp.ndarray, req_p: jnp.ndarray, feasible: jnp.ndarray) -> jnp.ndarray:
@@ -173,13 +171,13 @@ def simon_max_share_score(alloc: jnp.ndarray, req_p: jnp.ndarray, feasible: jnp.
 
 def minmax_apply(raw: jnp.ndarray, lo, hi) -> jnp.ndarray:
     rng = hi - lo
-    inv = jnp.where(rng > 0, MAX_SCORE / jnp.where(rng > 0, rng, 1.0), 0.0)
-    return (raw - lo) * inv
+    inv = jnp.where(rng > 0, div(MAX_SCORE, jnp.where(rng > 0, rng, 1.0)), 0.0)
+    return mul(raw - lo, inv)
 
 
 def max_apply(raw: jnp.ndarray, hi, reverse: bool = False) -> jnp.ndarray:
-    inv = jnp.where(hi > 0, MAX_SCORE / jnp.where(hi > 0, hi, 1.0), 0.0)
-    return MAX_SCORE - raw * inv if reverse else raw * inv
+    inv = jnp.where(hi > 0, div(MAX_SCORE, jnp.where(hi > 0, hi, 1.0)), 0.0)
+    return MAX_SCORE - mul(raw, inv) if reverse else mul(raw, inv)
 
 
 def spread_apply(raw: jnp.ndarray, s_min, s_max, node_ok: jnp.ndarray,
@@ -190,10 +188,10 @@ def spread_apply(raw: jnp.ndarray, s_min, s_max, node_ok: jnp.ndarray,
     into the scalars."""
     pos = s_max > 0
     soft = any_soft.astype(jnp.float32)
-    inv = jnp.where(pos, 100.0 / jnp.maximum(s_max, 1e-9), 0.0) * soft
+    inv = jnp.where(pos, div(100.0, jnp.maximum(s_max, 1e-9)), 0.0) * soft
     base = jnp.where(pos, 0.0, 100.0) * soft
     c1 = s_max + s_min
-    return jnp.where(node_ok, base + (c1 - raw) * inv, 0.0)
+    return jnp.where(node_ok, base + mul(c1 - raw, inv), 0.0)
 
 
 def node_affinity_score(class_na_row: jnp.ndarray, feasible: jnp.ndarray) -> jnp.ndarray:

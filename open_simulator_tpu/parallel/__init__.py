@@ -11,9 +11,8 @@ not hand-written collectives).
 
 Mesh axes:
   "scenario" — data-parallel over what-if scenarios (the throughput axis)
-  "node"     — model-parallel over the cluster's node axis, for clusters
-               too large for one chip's HBM (reduction collectives over
-               argmax/min are inserted by GSPMD)
+  "node"     — held at 1: a node split placed pods wrongly on a TPU mesh
+               and is refused until a chip run verifies it (ROADMAP B3)
 """
 
 from open_simulator_tpu.parallel.sweep import (

@@ -41,10 +41,9 @@ def initialize_multihost(
     jax.distributed.initialize(**kwargs)
 
 
-def global_scenario_mesh(n_node_axis: int = 1):
-    """A mesh over every device in the job (all hosts), scenario-major.
-    Raises if n_node_axis does not divide the device count — a host whose
-    devices fell out of the mesh would hang, not error. Feed lane batches
-    via jax.make_array_from_process_local_data so each host materializes
+def global_scenario_mesh():
+    """A mesh over every device in the job (all hosts), every device on
+    the scenario axis. Feed lane batches via
+    jax.make_array_from_process_local_data so each host materializes
     only its shard."""
-    return make_mesh(n_node=n_node_axis, require_all=True)
+    return make_mesh(require_all=True)

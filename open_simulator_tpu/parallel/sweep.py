@@ -24,12 +24,12 @@ from typing import Any, Dict, List, NamedTuple, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh
 
 from open_simulator_tpu.encode.snapshot import ClusterSnapshot
 from open_simulator_tpu.engine.exec_cache import (
     bucketed_device_arrays,
-    enable_persistent_cache,
+    refuse_node_axis,
     run_batched_cached,
     run_mesh_cached,
 )
@@ -121,11 +121,13 @@ def make_mesh(
     require_all: bool = False,
     devices: Optional[Sequence] = None,
 ) -> Mesh:
-    """Build a ("scenario", "node") mesh over the available devices.
-    Defaults to all devices on the scenario axis (pure data parallel).
+    """Build a ("scenario", "node") mesh over the available devices, all
+    of them on the scenario axis by default. The "node" axis stays 1:
+    a larger one is refused (`exec_cache.refuse_node_axis`, ROADMAP B3).
     Unused trailing devices are dropped unless require_all — multi-host
     callers must not silently exclude a host's devices (a host with no
     addressable shard hangs instead of erroring)."""
+    refuse_node_axis(n_node)
     devs = np.array(jax.devices() if devices is None else list(devices))
     if n_scenario is None:
         n_scenario = len(devs) // n_node
@@ -194,38 +196,6 @@ def batched_schedule(
     return run_mesh_cached(arrs, active_batch, cfg, mesh, carry=carry,
                            waves=waves, weights=weights,
                            retries=retries, backoff_s=backoff_s)
-
-
-def shard_arrays(arrs, mesh: Mesh):
-    """Place the snapshot arrays on the mesh with the node axis sharded
-    over the "node" mesh axis (model parallelism for clusters whose state
-    exceeds one chip's HBM). Pod-axis and vocab arrays are replicated;
-    GSPMD inserts the all-gathers/argmax reductions the scan step needs.
-
-    The node-axis position per array comes from the canonical
-    declarations next to the dataclass (encode/snapshot.py
-    NODE_AXIS_FIRST/NODE_AXIS_SECOND, shared with the bucketing pad —
-    shape heuristics would misfire when P happens to equal N).
-    """
-    from open_simulator_tpu.encode.snapshot import (
-        NODE_AXIS_FIRST,
-        NODE_AXIS_SECOND,
-    )
-
-    def spec_for(name: str, x) -> P:
-        if name in NODE_AXIS_FIRST:
-            return P("node", *([None] * (x.ndim - 1)))
-        if name in NODE_AXIS_SECOND:
-            return P(None, "node", *([None] * (x.ndim - 2)))
-        return P(*([None] * x.ndim))
-
-    import dataclasses
-
-    placed = {}
-    for f in dataclasses.fields(arrs):
-        x = getattr(arrs, f.name)
-        placed[f.name] = jax.device_put(x, NamedSharding(mesh, spec_for(f.name, x)))
-    return type(arrs)(**placed)
 
 
 def active_masks_for_counts(snapshot: ClusterSnapshot, counts: Sequence[int]) -> np.ndarray:
@@ -332,7 +302,6 @@ def capacity_sweep(
     # deadline observed before the batch launches: the exhaustive sweep
     # is one device program, so its only cooperative boundary is here
     lifecycle.check_current("exhaustive sweep start")
-    enable_persistent_cache(cfg.compile_cache_dir)
     arrs, _, n_pods = bucketed_device_arrays(snapshot.arrays)
     masks = _padded_lane_masks(
         active_masks_for_counts(snapshot, counts), arrs.alloc.shape[0])
@@ -485,7 +454,6 @@ def capacity_bisect(
 
     if max_new < 0:
         raise ValueError(f"max_new must be >= 0, got {max_new}")
-    enable_persistent_cache(cfg.compile_cache_dir)
     arrs, _, n_pods = bucketed_device_arrays(snapshot.arrays)
     n_pad = arrs.alloc.shape[0]
     alloc = np.asarray(arrs.alloc)
@@ -652,6 +620,11 @@ def _execute_sweep(arrs, masks, sweep_cfg, mesh, fail_reasons,
     import time as _time
 
     from open_simulator_tpu.resilience import faults
+
+    if mesh is not None:
+        # before the fault ladder, which would turn the refusal into
+        # isolated lanes that each fail the same way
+        refuse_node_axis(int(dict(mesh.shape).get("node", 1)))
     from open_simulator_tpu.resilience.retry import run_with_retries
     from open_simulator_tpu.telemetry import registry as _telemetry
 

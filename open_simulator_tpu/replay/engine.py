@@ -329,7 +329,6 @@ class _Program:
             self.snapshot, **dict(opts.config_overrides))._replace(
             forced_prefix=0, fail_reasons=False)
         self.cfg_defrag = self.cfg._replace(**DEFRAG_OVERRIDES)
-        exec_cache.enable_persistent_cache(self.cfg.compile_cache_dir)
 
         self.N = self.snapshot.n_nodes
         self.P = self.snapshot.n_pods

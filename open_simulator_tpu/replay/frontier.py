@@ -247,7 +247,6 @@ def capacity_frontier(cluster, apps, specs: List[NodeSpec],
                               with_volume_objects(None, cluster, apps))
     cfg = make_config(snapshot, **dict(config_overrides or {}))._replace(
         fail_reasons=False)
-    exec_cache.enable_persistent_cache(cfg.compile_cache_dir)
 
     with ledger.run_capture("frontier") as cap:
         arrs, n_nodes, n_pods = exec_cache.bucketed_device_arrays(

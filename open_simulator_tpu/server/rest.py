@@ -306,14 +306,13 @@ class SimulationServer:
         self._sessions = SessionStore(max_resident=max_sessions)
         self._sessions.scan()
         telemetry.install_runtime_gauges()
-        if compile_cache_dir:
-            # persistent XLA compilation cache: a restarted server skips
-            # cold compiles for every shape bucket it has served before
-            from open_simulator_tpu.engine.exec_cache import (
-                enable_persistent_cache,
-            )
+        # persistent XLA compilation cache: a restarted server skips
+        # cold compiles for every shape bucket it has served before
+        from open_simulator_tpu.engine.exec_cache import (
+            enable_persistent_cache,
+        )
 
-            enable_persistent_cache(compile_cache_dir)
+        enable_persistent_cache(compile_cache_dir)
 
     # ---- lifecycle -----------------------------------------------------
 

@@ -123,7 +123,6 @@ class Simulator:
         with span("encode"):
             snapshot = encode_cluster(self.cluster.nodes, self._pods, opts)
         cfg = make_config(snapshot, **self._overrides)
-        exec_cache.enable_persistent_cache(cfg.compile_cache_dir)
         with span("transfer"):
             # bucketed padding: each schedule_app() grows the pod sequence
             # by a few rows, which used to recompile the whole scan; inside

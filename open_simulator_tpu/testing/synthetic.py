@@ -18,14 +18,28 @@ import numpy as np
 def synthetic_snapshot(n_nodes: int = 64, n_pods: int = 256, max_new: int = 0,
                        rich: bool = False, pools: int = 0,
                        bound: float = 0.0):
-    """pools > 0 labels nodes into `pools` tenant pools and gives every
-    pod a matching nodeSelector (+ per-pool app groups) — the
+    """The synthetic cluster of `synthetic_objects`, encoded, with
+    `max_new` template node slots for the capacity sweep."""
+    from open_simulator_tpu.encode.snapshot import EncodeOptions, encode_cluster
+
+    nodes, pods, template = synthetic_objects(n_nodes, n_pods, rich=rich,
+                                              pools=pools, bound=bound)
+    opts = None
+    if max_new:
+        opts = EncodeOptions(max_new_nodes=max_new, new_node_template=template)
+    return encode_cluster(nodes, pods, opts)
+
+
+def synthetic_objects(n_nodes: int, n_pods: int, rich: bool = False,
+                      pools: int = 0, bound: float = 0.0):
+    """(nodes, pods, template node) as k8s objects, seeded: the same
+    objects every call. pools > 0 labels nodes into `pools` tenant pools
+    and gives every pod a matching nodeSelector (+ per-pool app groups) — the
     multi-tenant shape whose disjoint footprints the wave scheduler
     (engine/waves.py) batches. bound > 0 pre-binds that fraction of pods
     via spec.nodeName, interleaved through the sequence — the
     cluster-dump replay shape. Both default off and leave the rich /
     non-rich workloads byte-identical to the tracked bench series."""
-    from open_simulator_tpu.encode.snapshot import EncodeOptions, encode_cluster
     from open_simulator_tpu.k8s.objects import Node, Pod
 
     rng = np.random.RandomState(0)
@@ -142,7 +156,5 @@ def synthetic_snapshot(n_nodes: int = 64, n_pods: int = 256, max_new: int = 0,
 
     nodes = [mk_node(f"n{i}", i) for i in range(n_nodes)]
     pods = [mk_pod(i) for i in range(n_pods)]
-    opts = None
-    if max_new:
-        opts = EncodeOptions(max_new_nodes=max_new, new_node_template=mk_node("template"))
-    return encode_cluster(nodes, pods, opts)
+    # drawn last, so nodes and pods never depend on it
+    return nodes, pods, mk_node("template")

@@ -327,7 +327,6 @@ def tune_search(cluster, apps, opts: Optional[TuneOptions] = None,
                               with_volume_objects(None, cluster, apps))
     cfg = make_config(snapshot, traced_weights=True,
                       **overrides)._replace(fail_reasons=False)
-    exec_cache.enable_persistent_cache(cfg.compile_cache_dir)
     arrs, _, n_pods = exec_cache.bucketed_device_arrays(snapshot.arrays)
     n_pad = int(arrs.alloc.shape[0])
     active = np.zeros(n_pad, dtype=bool)

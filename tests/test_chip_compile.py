@@ -1,0 +1,134 @@
+"""Compile rehearsals for TPU v5e, run without the chip.
+
+The TPU compiler installed here compiles for a described v5e topology,
+so these tests catch what the chip's compiler would refuse (memory,
+partitioning) and check the compiled HLO, at no chip time. The topology
+is described inside a fixture only: describing it loads libtpu, which
+one process at a time may hold, so it must never happen at import.
+"""
+
+from __future__ import annotations
+
+import os
+import re
+
+import numpy as np
+import pytest
+
+V5E_HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def no_persistent_cache():
+    """A TPU executable written to the persistent cache cannot be read
+    back without a chip; keep these compiles out of it."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _lane_program(n_nodes, n_pods, max_new, lanes):
+    """The sweep's batched executable inputs, as the product builds them:
+    bucketed all-ops arrays, its wave plan, a zeros carry batch."""
+    import jax
+
+    from open_simulator_tpu.engine import exec_cache
+    from open_simulator_tpu.engine.scheduler import make_config
+    from open_simulator_tpu.engine.waves import waves_for
+    from open_simulator_tpu.testing.synthetic import synthetic_snapshot
+
+    snap = synthetic_snapshot(n_nodes, n_pods, max_new=max_new, rich=True)
+    cfg = make_config(snap)._replace(fail_reasons=False)
+    arrs = exec_cache.pad_snapshot_arrays(
+        snap.arrays, *exec_cache.bucket_shape(snap.n_nodes, snap.n_pods))
+    waves = waves_for(snap.arrays, cfg, n_pods_total=int(arrs.req.shape[0]))
+    carry = jax.eval_shape(
+        lambda a: exec_cache._zeros_carry_batch(a, cfg, lanes), arrs)
+    fn = exec_cache.batched_lane_fn(cfg, waves, False)
+    return fn, arrs, carry, (lanes, arrs.alloc.shape[0])
+
+
+def _shape(x, sharding):
+    import jax
+
+    return jax.ShapeDtypeStruct(np.shape(x), x.dtype, sharding=sharding)
+
+
+@pytest.fixture(scope="module")
+def single_chip_default(topo, no_persistent_cache):
+    """The `default` preset's executable (1,024 nodes x 2,048 all-ops
+    pods x 256 lanes) compiled for one v5e chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    one = SingleDeviceSharding(topo.devices[0])
+    fn, arrs, carry, mask_shape = _lane_program(1024, 2048, 8, 256)
+    tree = jax.tree_util.tree_map
+    return jax.jit(fn, donate_argnums=(2,)).lower(
+        tree(lambda x: _shape(x, one), arrs),
+        jax.ShapeDtypeStruct(mask_shape, jnp.bool_, sharding=one),
+        tree(lambda x: _shape(x, one), carry)).compile()
+
+
+def test_single_chip_executable_fits_v5e(single_chip_default):
+    mem = single_chip_default.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert 0 < total < V5E_HBM_BYTES, total
+
+
+def test_mesh_executable_compiles_on_2x2(topo, no_persistent_cache):
+    """run_mesh_cached's program on the four chips of a 2x2 v5e: lanes
+    split over a 4-wide "scenario" axis, the snapshot replicated (the
+    "node" axis stays 1, ROADMAP B3)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh
+
+    from open_simulator_tpu.engine.exec_cache import mesh_shardings
+
+    mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("scenario", "node"))
+    fn, arrs, carry, mask_shape = _lane_program(32, 128, 8, 8)
+    (arrs_sh, mask_sh, carry_sh, _), out_sh = mesh_shardings(arrs, carry, mesh)
+    tree = jax.tree_util.tree_map
+    compiled = jax.jit(
+        fn, donate_argnums=(2,), in_shardings=(arrs_sh, mask_sh, carry_sh),
+        out_shardings=out_sh,
+    ).lower(tree(lambda x: _shape(x, None), arrs),
+            jax.ShapeDtypeStruct(mask_shape, jnp.bool_),
+            tree(lambda x: _shape(x, None), carry)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
+    out = compiled.output_shardings
+    assert out.node.spec[0] == "scenario"
+    assert out.state.headroom.spec[0] == "scenario"
+
+
+def test_scan_dots_run_at_highest_precision(single_chip_default):
+    """The engine sums exact integer counts in f32 matmuls; the TPU's
+    default precision rounds f32 operands to bf16 (exact only to 256),
+    so every f32 dot/convolution must carry HIGHEST operand precision."""
+    hlo = single_chip_default.as_text()
+    dots = re.findall(r"= f32\[[^\n]*? (?:dot|convolution)\([^\n]*", hlo)
+    assert dots, "no f32 dot in the compiled scan: the guard checks nothing"
+    loose = [d[:160] for d in dots
+             if "operand_precision={highest,highest}" not in d]
+    assert not loose, loose

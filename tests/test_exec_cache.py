@@ -263,30 +263,86 @@ def test_mesh_exec_cache_reuse_and_donation():
     np.testing.assert_array_equal(np.asarray(out_w.node), nodes1)
 
 
-def test_persistent_cache_writes_executables(tmp_path):
-    """--compile-cache-dir must actually persist compiles: jax freezes its
+@pytest.fixture
+def cache_state():
+    """Hand later tests back the suite-wide cache conftest configures."""
+    import jax
+
+    saved = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", saved)
+    exec_cache._persistent_dir = None
+
+
+@pytest.mark.parametrize("env_set,flag_set", [(True, True), (True, False),
+                                              (False, True), (False, False)])
+def test_compile_cache_placement(tmp_path, monkeypatch, cache_state,
+                                 env_set, flag_set):
+    """JAX_COMPILATION_CACHE_DIR, when set, IS the cache — a
+    --compile-cache-dir flag never overrides it — after every entry point
+    starts (CLI main, the server); unset, the flag's path is used, else
+    one fixed path inside the checkout."""
+    import importlib
+
+    import jax
+
+    from open_simulator_tpu.server.rest import SimulationServer
+
+    cli = importlib.import_module("open_simulator_tpu.cli.main")
+
+    env_dir, flag_dir = str(tmp_path / "env"), str(tmp_path / "flag")
+    if env_set:
+        monkeypatch.setenv(exec_cache.CACHE_ENV, env_dir)
+    else:
+        monkeypatch.delenv(exec_cache.CACHE_ENV, raising=False)
+    flag = flag_dir if flag_set else ""
+    want = env_dir if env_set else flag_dir if flag_set else (
+        exec_cache.DEFAULT_CACHE_DIR)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert exec_cache.DEFAULT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+
+    monkeypatch.setattr(cli, "_replay_main", lambda args: 0)
+    argv = ["replay", "--cluster-config", ".", "--trace", "t.json"]
+    assert cli.main(argv + (["--compile-cache-dir", flag] if flag else [])) == 0
+    assert jax.config.jax_compilation_cache_dir == want
+    exec_cache._persistent_dir = None
+    jax.config.update("jax_compilation_cache_dir", None)
+    SimulationServer(compile_cache_dir=flag)
+    assert jax.config.jax_compilation_cache_dir == want
+    assert os.path.isdir(want)
+
+
+def test_persistent_cache_writes_executables(tmp_path, monkeypatch,
+                                            cache_state):
+    """The placed cache must actually persist compiles: jax freezes its
     on-disk cache as "disabled" on the first (import-time) compile, so
     enable_persistent_cache has to reset that state or restarts stay
     cold. A fresh-shaped simulate after enabling must write entries."""
-    exec_cache.enable_persistent_cache(str(tmp_path))
-    try:
-        cluster, apps = _cluster(3, 7)
-        # a weight no other test uses -> unique jit signature, so an
-        # earlier in-memory cache hit cannot mask the persistent write
-        simulate(cluster, apps, config_overrides={"w_least": 0.875})
-        names = os.listdir(tmp_path)
-        assert any("schedule_pods" in n for n in names), names[:5]
-    finally:
-        # restore: later tests must not inherit the tmp dir (they go back
-        # to the suite-wide cache conftest configures, if any)
-        import jax
-
-        jax.config.update("jax_compilation_cache_dir",
-                          os.environ.get("JAX_COMPILATION_CACHE_DIR"))
-        exec_cache._persistent_dir = None
+    monkeypatch.setenv(exec_cache.CACHE_ENV, str(tmp_path))
+    exec_cache.enable_persistent_cache("")
+    cluster, apps = _cluster(3, 7)
+    # a weight no other test uses -> unique jit signature, so an
+    # earlier in-memory cache hit cannot mask the persistent write
+    simulate(cluster, apps, config_overrides={"w_least": 0.875})
+    names = os.listdir(tmp_path)
+    assert any("schedule_pods" in n for n in names), names[:5]
 
 
 # ---- bisection sweep ----------------------------------------------------
+
+def test_removed_compile_cache_override_is_a_structured_error():
+    """The old config_overrides={"compile_cache_dir": ...} key fails with
+    E_SPEC pointing at enable_persistent_cache, instead of a TypeError or
+    a silently cold cache."""
+    import pytest
+
+    from open_simulator_tpu.errors import SimulationError
+
+    cluster, apps = _cluster(3, 2)
+    with pytest.raises(SimulationError, match="enable_persistent_cache") as e:
+        simulate(cluster, apps, config_overrides={"compile_cache_dir": "/x"})
+    assert e.value.code == "E_SPEC"
+
 
 def test_bisect_matches_exhaustive_and_dispatches_fewer_trials():
     """ISSUE 4 acceptance: capacity_bisect returns the exhaustive sweep's

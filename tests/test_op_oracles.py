@@ -10,7 +10,13 @@ import numpy as np
 import pytest
 
 from open_simulator_tpu.ops import filters, scores
-from open_simulator_tpu.ops.domains import domain_count, domain_min, same_domain
+from open_simulator_tpu.ops.domains import (
+    broadcast_domains,
+    domain_count,
+    domain_index,
+    domain_min,
+    same_domain,
+)
 
 
 def random_topology(rng, n, d):
@@ -41,6 +47,22 @@ def test_domain_count_oracle(seed):
         if ids[i] >= 0:
             want[i] = sum(counts[j] for j in range(n) if ids[j] == ids[i])
     np.testing.assert_allclose(got, want)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_domain_index_gather_equals_onehot_broadcast(seed):
+    """The scan's one-hot broadcasts became gathers by domain id: bit for
+    bit the `O @ v` they replaced (0 where a node lacks the key), for
+    vector and matrix per-domain values."""
+    rng = np.random.RandomState(seed)
+    n, d, q = 23, 6, 3
+    onehot, ids = random_topology(rng, n, d)
+    idx = np.asarray(domain_index(jnp.asarray(onehot)))[0]
+    np.testing.assert_array_equal(idx, np.where(ids < 0, d, ids))
+    per_dom = rng.randint(0, 400, size=(d, q)).astype(np.float32)
+    for v in (per_dom, per_dom[:, 0]):
+        got = np.asarray(broadcast_domains(jnp.asarray(v), jnp.asarray(idx)))
+        np.testing.assert_array_equal(got, onehot[0].astype(np.float64) @ v)
 
 
 @pytest.mark.parametrize("seed", range(5))

@@ -1,6 +1,7 @@
 """Capacity sweep + sharded scenario batch on the 8-device virtual mesh."""
 
 import jax
+import pytest
 import numpy as np
 
 from open_simulator_tpu.core import build_pod_sequence, AppResource
@@ -73,8 +74,8 @@ def test_mesh_bisect_donated_carry_digest_matches_single_device():
 
     snap = _snapshot()
     cfg = make_config(snap)
-    # 4x2: the scenario axis must divide the lane count (4 lanes below)
-    mesh = make_mesh(n_scenario=4, n_node=2)
+    # the scenario axis must divide the lane count (4 lanes below)
+    mesh = make_mesh(n_scenario=4)
 
     def miss():
         return counter("simon_compile_cache_total", "",
@@ -92,16 +93,13 @@ def test_mesh_bisect_donated_carry_digest_matches_single_device():
             == ledger.plan_digest(plan_single)["digest"])
 
 
-def test_node_axis_sharding_bit_equal_across_meshes():
-    """VERDICT r3: the node-axis sharding claim had no equality test. The
-    same snapshot swept on mesh shapes 1x1, 4x2, and 2x4 (scenario x node)
-    must produce bit-identical picks and fail counts — GSPMD resharding of
-    the node-state arrays cannot be allowed to change a single argmax."""
+def test_scenario_meshes_bit_equal_across_splits():
+    """The same snapshot swept over 1, 4 and 8 chips on the scenario axis
+    must produce bit-identical picks, fail counts and headroom."""
     from open_simulator_tpu.engine.scheduler import device_arrays
     from open_simulator_tpu.parallel.sweep import (
         active_masks_for_counts,
         batched_schedule,
-        shard_arrays,
     )
     import jax.numpy as jnp
 
@@ -111,10 +109,9 @@ def test_node_axis_sharding_bit_equal_across_meshes():
     masks = jnp.asarray(active_masks_for_counts(snap, counts))
 
     results = []
-    for n_scen, n_node in [(1, 1), (4, 2), (2, 4)]:
-        mesh = make_mesh(n_scenario=n_scen, n_node=n_node)
-        arrs = shard_arrays(device_arrays(snap), mesh)
-        out = batched_schedule(arrs, masks, cfg, mesh=mesh)
+    for n_scen in (1, 4, 8):
+        mesh = make_mesh(n_scenario=n_scen)
+        out = batched_schedule(device_arrays(snap), masks, cfg, mesh=mesh)
         results.append((np.asarray(out.node), np.asarray(out.fail_counts),
                         np.asarray(out.state.headroom)))
     base = results[0]
@@ -124,14 +121,13 @@ def test_node_axis_sharding_bit_equal_across_meshes():
         np.testing.assert_allclose(got[2], base[2], rtol=0, atol=0)
 
 
-def test_node_axis_sharding_with_spread_constraints():
-    """Node-sharded lanes with zone spread: the dom_count carry and hoisted
-    domain stats must survive node-axis partitioning bit-for-bit."""
+def test_scenario_meshes_with_spread_constraints():
+    """Mesh lanes with zone spread: the dom_count carry and hoisted domain
+    stats must come out bit-for-bit whatever the scenario split."""
     from open_simulator_tpu.engine.scheduler import device_arrays
     from open_simulator_tpu.parallel.sweep import (
         active_masks_for_counts,
         batched_schedule,
-        shard_arrays,
     )
     import jax.numpy as jnp
 
@@ -165,10 +161,9 @@ def test_node_axis_sharding_with_spread_constraints():
     masks = jnp.asarray(active_masks_for_counts(snap, counts))
 
     results = []
-    for n_scen, n_node in [(1, 1), (4, 2), (2, 4)]:
-        mesh = make_mesh(n_scenario=n_scen, n_node=n_node)
-        arrs = shard_arrays(device_arrays(snap), mesh)
-        out = batched_schedule(arrs, masks, cfg, mesh=mesh)
+    for n_scen in (1, 2, 4):
+        mesh = make_mesh(n_scenario=n_scen)
+        out = batched_schedule(device_arrays(snap), masks, cfg, mesh=mesh)
         results.append(np.asarray(out.node))
     np.testing.assert_array_equal(results[1], results[0])
     np.testing.assert_array_equal(results[2], results[0])
@@ -181,46 +176,42 @@ def test_make_mesh_require_all_rejects_partial_use():
 
     n = len(jax.devices())
     assert n == 8
-    # 3x2 = 6 of 8 devices: fine by default, rejected with require_all
-    mesh = make_mesh(n_scenario=3, n_node=2)
+    # 6 of 8 devices: fine by default, rejected with require_all
+    mesh = make_mesh(n_scenario=6)
     assert mesh.devices.size == 6
     with pytest.raises(ValueError, match="uses 6 of 8 devices"):
-        make_mesh(n_scenario=3, n_node=2, require_all=True)
+        make_mesh(n_scenario=6, require_all=True)
     # an oversubscribed mesh always errors
     with pytest.raises(ValueError, match="needs 16 devices"):
-        make_mesh(n_scenario=8, n_node=2)
+        make_mesh(n_scenario=16)
 
 
-def test_shard_arrays_axis_placement_when_n_nodes_equals_n_pods():
-    """The docstring's warning case: with n_nodes == n_pods a shape
-    heuristic could shard the pod axis by accident. The declared sets
-    must put node-first arrays on axis 0 and node-second on axis 1, and
-    leave pod-axis arrays replicated."""
+@pytest.mark.parametrize("how", ["make_mesh", "hand_built_mesh",
+                                 "capacity_sweep"])
+def test_node_axis_is_refused_naming_b3(how):
+    """A node split placed pods wrongly on a 2x2 v5e mesh (PR 21): a
+    "node" axis above 1 fails loudly — from make_mesh, and from the mesh
+    executable for a Mesh built by hand — instead of running."""
+    from jax.sharding import Mesh
+
     from open_simulator_tpu.engine.scheduler import device_arrays
-    from open_simulator_tpu.parallel.sweep import shard_arrays
+    from open_simulator_tpu.parallel.sweep import (
+        active_masks_for_counts,
+        batched_schedule,
+    )
 
-    cluster = ClusterResources()
-    cluster.nodes = [make_node(f"n{i}", cpu_m=4000, mem_mib=8192)
-                     for i in range(8)]
-    app = ClusterResources()
-    app.pods = [make_pod(f"p{i}", cpu="100m", mem="64Mi") for i in range(8)]
-    pods = build_pod_sequence(cluster, [AppResource(name="a", resources=app)])
-    snap = encode_cluster([make_valid_node(n) for n in cluster.nodes], pods)
-    assert snap.n_nodes == snap.n_pods == 8  # the ambiguous shape
-
-    mesh = make_mesh(n_scenario=4, n_node=2)
-    placed = shard_arrays(device_arrays(snap), mesh)
-
-    def axes(x):
-        return getattr(x.sharding, "spec", None)
-
-    assert tuple(axes(placed.alloc)) == ("node", None)        # node-first
-    assert tuple(axes(placed.active)) == ("node",)
-    assert tuple(axes(placed.topo_onehot)) == (None, "node", None)  # node-second
-    assert tuple(axes(placed.class_affinity)) == (None, "node")
-    # pod-axis arrays replicated — every entry None
-    assert all(s is None for s in tuple(axes(placed.req)))
-    assert all(s is None for s in tuple(axes(placed.forced_node)))
+    with pytest.raises(ValueError, match="ROADMAP B3"):
+        if how == "make_mesh":
+            make_mesh(n_scenario=2, n_node=2)
+        snap = _snapshot(n_pods=4, max_new=3)
+        mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2),
+                    ("scenario", "node"))
+        if how == "hand_built_mesh":
+            batched_schedule(device_arrays(snap),
+                             active_masks_for_counts(snap, [0, 1]),
+                             make_config(snap), mesh=mesh)
+        # the sweep refuses before its fault ladder could isolate lanes
+        capacity_sweep(snap, make_config(snap), [0, 1], mesh=mesh)
 
 
 def test_isolated_lane_pick_shape_mismatch_is_recorded(monkeypatch):
@@ -274,18 +265,17 @@ def test_all_lanes_failed_message_survives_any_lane_numbering(monkeypatch):
         sweep_mod.capacity_sweep(snap, cfg, [0, 1], backoff_s=0.0)
 
 
-def test_node_axis_sharding_bit_equal_all_ops():
-    """Same mesh-shape equality as above, but on the all-ops workload —
+def test_scenario_meshes_bit_equal_all_ops():
+    """Same mesh-split equality as above, but on the all-ops workload —
     the sparse-slot column updates (dynamic-update-slice on the sharded
-    carries), affinity/anti-affinity/spread ops, and ports must survive
-    GSPMD resharding bit-for-bit too."""
+    carries), affinity/anti-affinity/spread ops, and ports must come out
+    bit-for-bit too."""
     import __graft_entry__ as ge
     import jax.numpy as jnp
     from open_simulator_tpu.engine.scheduler import device_arrays
     from open_simulator_tpu.parallel.sweep import (
         active_masks_for_counts,
         batched_schedule,
-        shard_arrays,
     )
 
     snap = ge._synthetic_snapshot(n_nodes=8, n_pods=48, max_new=8, rich=True)
@@ -295,10 +285,9 @@ def test_node_axis_sharding_bit_equal_all_ops():
     masks = jnp.asarray(active_masks_for_counts(snap, counts))
 
     results = []
-    for n_scen, n_node in [(1, 1), (4, 2), (2, 4)]:
-        mesh = make_mesh(n_scenario=n_scen, n_node=n_node)
-        arrs = shard_arrays(device_arrays(snap), mesh)
-        out = batched_schedule(arrs, masks, cfg, mesh=mesh)
+    for n_scen in (1, 4, 8):
+        mesh = make_mesh(n_scenario=n_scen)
+        out = batched_schedule(device_arrays(snap), masks, cfg, mesh=mesh)
         results.append((np.asarray(out.node), np.asarray(out.fail_counts),
                         np.asarray(out.state.headroom),
                         np.asarray(out.state.term_block),

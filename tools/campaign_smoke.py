@@ -1,6 +1,10 @@
 #!/usr/bin/env python
 """Campaign smoke: the fleet fault-isolation contract end to end.
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Stages (`make campaign-smoke`, also a tools/smoke.sh stage):
 
 1. A 3-cluster fixture fleet (one deliberately malformed) runs through

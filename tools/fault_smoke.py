@@ -2,6 +2,10 @@
 """Device-fault-domain smoke: a REAL server under an injected fault plan
 (`make fault-smoke`, also a tools/smoke.sh stage).
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Stages (ISSUE 14, ARCHITECTURE.md §18):
 
 1. Healthy reference: a clean server admits the cluster and answers the

@@ -3,6 +3,10 @@
 contract against a REAL server process (`make journal-smoke`, also a
 tools/smoke.sh stage).
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Stages (ISSUE 16, ARCHITECTURE.md §19):
 
 1. Create TWO journaled sessions on a live server, feed events, record

@@ -1,6 +1,10 @@
 """Lifecycle smoke: graceful drain end to end against a REAL server
 process (tools/smoke.sh stage, `make lifecycle-smoke`).
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Scenario (ISSUE 6 satellite): start `simon-tpu server`, put one request
 in flight, SIGTERM the process, then assert
 

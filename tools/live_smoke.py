@@ -3,6 +3,10 @@
 ledger, and `simon-tpu top` against a REAL server process
 (`make live-smoke`, also a tools/smoke.sh stage).
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Stages (ARCHITECTURE.md §21):
 
 1. Causal stream: an SSE subscriber on GET /api/events?follow=1 watches

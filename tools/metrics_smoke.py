@@ -1,6 +1,10 @@
 """Smoke stage: boot the REST server, simulate once over HTTP, scrape
 /metrics, and assert the core series are present (tools/smoke.sh).
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Runs the real ThreadingHTTPServer on a loopback port (not handler calls
 in-process) so the scrape exercises exactly what an operator's Prometheus
 would: request accounting, the scheduling-phase histogram, simulation

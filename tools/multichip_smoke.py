@@ -1,7 +1,11 @@
 """Multi-chip digest-equality + recompile gate (`make multichip-smoke`).
 
-Runs `batched_schedule` over an 8-virtual-CPU-device ("scenario" x
-"node") mesh and asserts the node assignments — and their ledger result
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
+Runs `batched_schedule` over an 8-virtual-CPU-device "scenario" mesh
+and asserts the node assignments — and their ledger result
 digest — are IDENTICAL to the single-device run of the same workload.
 The MULTICHIP_r01–r05 records all silently carried the same pre-PR-1
 scan-arity crash because nothing gated the sharded path between rounds;
@@ -59,7 +63,7 @@ def _mesh_misses() -> float:
 def main() -> int:
     import __graft_entry__ as ge
 
-    devices = ge._virtual_cpu_devices(N_DEVICES)
+    devices = ge._cpu_devices(N_DEVICES)
     import jax.numpy as jnp
     import numpy as np
 
@@ -72,12 +76,11 @@ def main() -> int:
         active_masks_for_counts,
         batched_schedule,
         make_mesh,
-        shard_arrays,
     )
     from open_simulator_tpu.telemetry import ledger
     from open_simulator_tpu.telemetry.ledger import array_result_digest
 
-    mesh = make_mesh(n_scenario=N_DEVICES // 2, n_node=2, devices=devices)
+    mesh = make_mesh(n_scenario=N_DEVICES, devices=devices)
     failures = 0
     for name, kw in (
         ("easy", {}),
@@ -97,7 +100,7 @@ def main() -> int:
                                       waves=plan)
         nodes_single = np.asarray(out_single.node)
 
-        arrs_mesh = shard_arrays(device_arrays(snap), mesh)
+        arrs_mesh = device_arrays(snap)
         out_mesh = batched_schedule(arrs_mesh, masks, cfg, mesh=mesh,
                                     waves=plan)
         nodes_mesh = np.asarray(out_mesh.node)

@@ -1,6 +1,10 @@
 #!/usr/bin/env python
 """Replay smoke: the time-axis contract end to end.
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Stages (`make replay-smoke`, also a tools/smoke.sh stage):
 
 1. A synthetic day-in-the-cluster (arrival waves, departures, one

@@ -2,6 +2,10 @@
 """Serving smoke: the inference-grade path against a REAL server process
 (`make serve-smoke`, also a tools/smoke.sh stage).
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Stages (ISSUE 12):
 
 1. Admit once, probe many: a full POST to /api/simulate returns the

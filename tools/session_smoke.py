@@ -2,6 +2,10 @@
 """Digital-twin session smoke: the crash-safety contract against a REAL
 server process (`make session-smoke`, also a tools/smoke.sh stage).
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Stages (ISSUE 11):
 
 1. Create a journaled session on a live server (synthetic cluster +

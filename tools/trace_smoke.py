@@ -2,6 +2,10 @@
 """Causal-tracing smoke: trace ids + the black box against a REAL server
 process (`make trace-smoke`, also a tools/smoke.sh stage).
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Stages (ARCHITECTURE.md §20):
 
 1. Client-supplied trace id: POST /api/simulate with `X-Simon-Trace-Id`

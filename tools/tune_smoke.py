@@ -2,6 +2,10 @@
 """Tune smoke: the policy-search path against a REAL server process
 (`make tune-smoke`, also a tools/smoke.sh stage).
 
+A CPU rehearsal: it and every process it starts run with
+JAX_PLATFORMS=cpu and never touch the chip; `chip_smoke.py` is the
+chip path.
+
 Stages (ISSUE 13):
 
 1. Grid round: POST /api/tune sweeps a coordinate grid as lanes of one
