@@ -242,15 +242,14 @@ class Applier:
         with span("expand"):
             pods = build_pod_sequence(cluster, apps, use_greed=self.opts.use_greed)
         max_new = self.opts.max_new_nodes if template is not None else 0
-        with span("encode"):
-            snapshot = encode_cluster(
-                cluster.nodes,
-                pods,
-                with_volume_objects(
-                    EncodeOptions(max_new_nodes=max_new, new_node_template=template),
-                    cluster, apps,
-                ),
-            )
+        snapshot = encode_cluster(
+            cluster.nodes,
+            pods,
+            with_volume_objects(
+                EncodeOptions(max_new_nodes=max_new, new_node_template=template),
+                cluster, apps,
+            ),
+        )
         overrides = {}
         if self.opts.default_scheduler_config:
             from open_simulator_tpu.engine.sched_config import weight_overrides_from_file

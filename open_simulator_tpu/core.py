@@ -316,8 +316,7 @@ def simulate(
         with span("expand"):
             pods = build_pod_sequence(cluster, apps, use_greed=use_greed)
         encode_options = with_volume_objects(encode_options, cluster, apps)
-        with span("encode"):
-            snapshot = encode_cluster(nodes, pods, encode_options)
+        snapshot = encode_cluster(nodes, pods, encode_options)
         cfg = make_config(snapshot, **config_overrides)
         with span("transfer"):
             # bucketed padding: snapshots in the same shape bucket present

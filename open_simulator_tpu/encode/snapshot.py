@@ -30,6 +30,7 @@ from open_simulator_tpu.k8s.selectors import (
     required_node_affinity_match,
     tolerates_taints,
 )
+from open_simulator_tpu.telemetry.spans import span
 
 _log = logging.getLogger(__name__)
 
@@ -314,9 +315,19 @@ def encode_cluster(
     pods: List[Pod],
     options: Optional[EncodeOptions] = None,
 ) -> ClusterSnapshot:
-    """Encode (nodes + optional padded new-node slots, ordered pods) into arrays."""
-    opts = options or EncodeOptions()
+    """Encode (nodes + optional padded new-node slots, ordered pods) into
+    arrays, inside the span "encode" with a child span for each of its
+    five largest sections: encode.topology (topology keys and domains),
+    encode.groups (selector groups and term registries), encode.classes
+    (compat classes), encode.pods (ports, requests, GPU, storage and
+    volume arrays) and encode.terms (ragged terms padded, the arrays
+    assembled)."""
+    with span("encode"):
+        return _encode_cluster(nodes, pods, options or EncodeOptions())
 
+
+def _encode_cluster(nodes: List[Node], pods: List[Pod],
+                    opts: EncodeOptions) -> ClusterSnapshot:
     all_nodes = [n for n in nodes]
     n_real = len(all_nodes)
     if opts.max_new_nodes > 0:
@@ -372,484 +383,489 @@ def encode_cluster(
     is_new = np.zeros(N, dtype=bool)
     is_new[n_real:] = True
 
-    # ---- topology keys & domains --------------------------------------
-    topo_vocab = _Vocab()
-    topo_vocab.add(HOSTNAME_KEY)
+    with span("encode.topology"):
+        # ---- topology keys & domains --------------------------------------
+        topo_vocab = _Vocab()
+        topo_vocab.add(HOSTNAME_KEY)
 
-    def _register_topo(key: str) -> int:
-        return topo_vocab.add(key or HOSTNAME_KEY)
+        def _register_topo(key: str) -> int:
+            return topo_vocab.add(key or HOSTNAME_KEY)
 
-    group_vocab = _Vocab()
-    group_sel: List[Tuple[LabelSelector, Tuple[str, ...]]] = []
+        group_vocab = _Vocab()
+        group_sel: List[Tuple[LabelSelector, Tuple[str, ...]]] = []
 
-    def _register_group(sel: Optional[LabelSelector], namespaces: Sequence[str]) -> int:
-        gk = _selector_group_key(sel, namespaces)
-        if gk is None:
-            gk = ("__nothing__",)
-            sel = LabelSelector(match_labels={"__never__": "__never__"})
-        before = len(group_vocab)
-        gid = group_vocab.add(gk)
-        if len(group_vocab) > before:
-            group_sel.append((sel, tuple(namespaces)))
-        return gid
+        def _register_group(sel: Optional[LabelSelector], namespaces: Sequence[str]) -> int:
+            gk = _selector_group_key(sel, namespaces)
+            if gk is None:
+                gk = ("__nothing__",)
+                sel = LabelSelector(match_labels={"__never__": "__never__"})
+            before = len(group_vocab)
+            gid = group_vocab.add(gk)
+            if len(group_vocab) > before:
+                group_sel.append((sel, tuple(namespaces)))
+            return gid
 
-    def _register_owner_group(ns: str, kind: str, name: str) -> int:
-        """Selector group keyed on workload identity — the stand-in for the
-        default-spread selector the vendored plugin derives from the pod's
-        owning service/ReplicaSet/StatefulSet (default_plugins.go system
-        defaults)."""
-        gk = ("__owner__", ns, kind, name)
-        before = len(group_vocab)
-        gid = group_vocab.add(gk)
-        if len(group_vocab) > before:
-            group_sel.append(("__owner__", (ns, kind, name)))
-        return gid
+        def _register_owner_group(ns: str, kind: str, name: str) -> int:
+            """Selector group keyed on workload identity — the stand-in for the
+            default-spread selector the vendored plugin derives from the pod's
+            owning service/ReplicaSet/StatefulSet (default_plugins.go system
+            defaults)."""
+            gk = ("__owner__", ns, kind, name)
+            before = len(group_vocab)
+            gid = group_vocab.add(gk)
+            if len(group_vocab) > before:
+                group_sel.append(("__owner__", (ns, kind, name)))
+            return gid
 
-    term_vocab = _Vocab()       # (gid, kid) -> tid, for required anti-affinity
-    pref_term_vocab = _Vocab()  # (gid, kid) -> t2id, for preferred terms
-                                # (the existing-pods scoring direction,
-                                # interpodaffinity/scoring.go)
+        term_vocab = _Vocab()       # (gid, kid) -> tid, for required anti-affinity
+        pref_term_vocab = _Vocab()  # (gid, kid) -> t2id, for preferred terms
+                                    # (the existing-pods scoring direction,
+                                    # interpodaffinity/scoring.go)
 
-    pod_aff_terms: List[List[Tuple[int, int, bool]]] = []
-    pod_anti_terms: List[List[Tuple[int, int]]] = []
-    pod_spread: List[List[Tuple[int, int, float, bool]]] = []
-    pod_pref: List[List[Tuple[int, int, float]]] = []
+        pod_aff_terms: List[List[Tuple[int, int, bool]]] = []
+        pod_anti_terms: List[List[Tuple[int, int]]] = []
+        pod_spread: List[List[Tuple[int, int, float, bool]]] = []
+        pod_pref: List[List[Tuple[int, int, float]]] = []
 
-    for p in pods:
-        affs = []
-        for t in p.pod_affinity_required:
-            gid = _register_group(t.selector, t.namespaces)
-            kid = _register_topo(t.topology_key)
-            self_match = (
-                labels_match_selector(p.meta.labels, t.selector) and p.meta.namespace in t.namespaces
-            )
-            affs.append((gid, kid, self_match))
-        pod_aff_terms.append(affs)
+        for p in pods:
+            affs = []
+            for t in p.pod_affinity_required:
+                gid = _register_group(t.selector, t.namespaces)
+                kid = _register_topo(t.topology_key)
+                self_match = (
+                    labels_match_selector(p.meta.labels, t.selector) and p.meta.namespace in t.namespaces
+                )
+                affs.append((gid, kid, self_match))
+            pod_aff_terms.append(affs)
 
-        antis = []
-        for t in p.pod_anti_affinity_required:
-            gid = _register_group(t.selector, t.namespaces)
-            kid = _register_topo(t.topology_key)
-            term_vocab.add((gid, kid))
-            antis.append((gid, kid))
-        pod_anti_terms.append(antis)
+            antis = []
+            for t in p.pod_anti_affinity_required:
+                gid = _register_group(t.selector, t.namespaces)
+                kid = _register_topo(t.topology_key)
+                term_vocab.add((gid, kid))
+                antis.append((gid, kid))
+            pod_anti_terms.append(antis)
 
-        spreads = []
-        for c in p.topology_spread:
-            gid = _register_group(c.label_selector, (p.meta.namespace,))
-            kid = _register_topo(c.topology_key)
-            spreads.append((gid, kid, float(c.max_skew), c.when_unsatisfiable == "DoNotSchedule"))
-        if not spreads and p.meta.owner_name and not p.node_name:
-            # v1beta2 system-default soft constraints for workload pods:
-            # zone maxSkew=3 + hostname maxSkew=5, ScheduleAnyway
-            gid = _register_owner_group(p.meta.namespace, p.meta.owner_kind, p.meta.owner_name)
-            spreads.append((gid, _register_topo("topology.kubernetes.io/zone"), 3.0, False))
-            spreads.append((gid, 0, 5.0, False))
-        pod_spread.append(spreads)
+            spreads = []
+            for c in p.topology_spread:
+                gid = _register_group(c.label_selector, (p.meta.namespace,))
+                kid = _register_topo(c.topology_key)
+                spreads.append((gid, kid, float(c.max_skew), c.when_unsatisfiable == "DoNotSchedule"))
+            if not spreads and p.meta.owner_name and not p.node_name:
+                # v1beta2 system-default soft constraints for workload pods:
+                # zone maxSkew=3 + hostname maxSkew=5, ScheduleAnyway
+                gid = _register_owner_group(p.meta.namespace, p.meta.owner_kind, p.meta.owner_name)
+                spreads.append((gid, _register_topo("topology.kubernetes.io/zone"), 3.0, False))
+                spreads.append((gid, 0, 5.0, False))
+            pod_spread.append(spreads)
 
-        prefs = []
-        for t in p.pod_affinity_preferred:
-            gid = _register_group(t.selector, t.namespaces)
-            kid = _register_topo(t.topology_key)
-            pref_term_vocab.add((gid, kid))
-            prefs.append((gid, kid, float(t.weight or 1)))
-        for t in p.pod_anti_affinity_preferred:
-            gid = _register_group(t.selector, t.namespaces)
-            kid = _register_topo(t.topology_key)
-            pref_term_vocab.add((gid, kid))
-            prefs.append((gid, kid, -float(t.weight or 1)))
-        pod_pref.append(prefs)
+            prefs = []
+            for t in p.pod_affinity_preferred:
+                gid = _register_group(t.selector, t.namespaces)
+                kid = _register_topo(t.topology_key)
+                pref_term_vocab.add((gid, kid))
+                prefs.append((gid, kid, float(t.weight or 1)))
+            for t in p.pod_anti_affinity_preferred:
+                gid = _register_group(t.selector, t.namespaces)
+                kid = _register_topo(t.topology_key)
+                pref_term_vocab.add((gid, kid))
+                prefs.append((gid, kid, -float(t.weight or 1)))
+            pod_pref.append(prefs)
 
-    K = len(topo_vocab)
-    K1 = max(K - 1, 1)
-    S = max(len(group_vocab), 1)
-    T = max(len(term_vocab), 1)
+        K = len(topo_vocab)
+        K1 = max(K - 1, 1)
+        S = max(len(group_vocab), 1)
+        T = max(len(term_vocab), 1)
 
-    # Domain encoding for non-hostname keys.
-    domain_vals: List[Dict[str, int]] = [dict() for _ in range(K1)]
-    topo_val = np.zeros((K1, N), dtype=np.int64)
-    has_key = np.zeros((K, N), dtype=np.float32)
-    for i, n in enumerate(all_nodes):
-        labels = n.meta.labels
-        has_key[0, i] = 1.0  # hostname: every node is its own domain
-        for kid in range(1, K):
-            key = topo_vocab.items[kid]
-            if key in labels:
-                has_key[kid, i] = 1.0
-                dv = domain_vals[kid - 1]
-                val = labels[key]
-                if val not in dv:
-                    dv[val] = len(dv)
-                topo_val[kid - 1, i] = dv[val]
-            else:
-                topo_val[kid - 1, i] = -1
-    D = max(opts.min_domain_pad, max((len(d) for d in domain_vals), default=1), 1)
-    topo_onehot = np.zeros((K1, N, D), dtype=np.float32)
-    for kk in range(K1):
-        for i in range(N):
-            v = topo_val[kk, i]
-            if v >= 0:
-                topo_onehot[kk, i, v] = 1.0
-
-    # ---- selector-group membership ------------------------------------
-    # Memoized per distinct (labels, namespace, owner): workload replicas
-    # share identity, so 50k pods usually mean only dozens of distinct rows.
-    match_groups = np.zeros((len(pods), S), dtype=bool)
-    _row_cache: Dict[tuple, np.ndarray] = {}
-    for pi, p in enumerate(pods):
-        cache_key = (
-            tuple(sorted(p.meta.labels.items())), p.meta.namespace,
-            p.meta.owner_kind, p.meta.owner_name,
-        )
-        row = _row_cache.get(cache_key)
-        if row is None:
-            row = np.zeros(S, dtype=bool)
-            for gid, (sel, namespaces) in enumerate(group_sel):
-                if sel == "__owner__":
-                    ns, kind, name = namespaces
-                    row[gid] = (
-                        p.meta.namespace == ns
-                        and p.meta.owner_kind == kind
-                        and p.meta.owner_name == name
-                    )
-                elif p.meta.namespace in namespaces and labels_match_selector(p.meta.labels, sel):
-                    row[gid] = True
-            _row_cache[cache_key] = row
-        match_groups[pi] = row
-
-    # ---- anti-affinity term registry ----------------------------------
-    term_key_arr = np.zeros(T, dtype=np.int64)
-    for (gid, kid), tid in term_vocab.index.items():
-        term_key_arr[tid] = kid
-    own_terms = np.zeros((len(pods), T), dtype=bool)
-    hit_terms = np.zeros((len(pods), T), dtype=bool)
-    for pi in range(len(pods)):
-        for gid, kid in pod_anti_terms[pi]:
-            own_terms[pi, term_vocab.index[(gid, kid)]] = True
-    for (gid, kid), tid in term_vocab.index.items():
-        hit_terms[:, tid] = match_groups[:, gid]
-    match_gid = slot_indices(match_groups)
-    own_tid = slot_indices(own_terms)
-    hit_tid = slot_indices(hit_terms)
-
-    # ---- preferred-term registry (existing-pods scoring direction) ----
-    T2 = max(len(pref_term_vocab), 1)
-    pref_term_key_arr = np.zeros(T2, dtype=np.int64)
-    for (gid, kid), tid in pref_term_vocab.index.items():
-        pref_term_key_arr[tid] = kid
-    hit_pref_terms = np.zeros((len(pods), T2), dtype=bool)
-    for (gid, kid), tid in pref_term_vocab.index.items():
-        hit_pref_terms[:, tid] = match_groups[:, gid]
-
-    # ---- compat classes ------------------------------------------------
-    class_vocab = _Vocab()
-    class_pods: List[Pod] = []
-    class_id = np.zeros(len(pods), dtype=np.int64)
-    for pi, p in enumerate(pods):
-        sig = (
-            tuple(sorted(p.node_selector.items())),
-            json.dumps(p.node_affinity_required, sort_keys=True) if p.node_affinity_required else "",
-            json.dumps(p.node_affinity_preferred, sort_keys=True) if p.node_affinity_preferred else "",
-            tuple((t.key, t.operator, t.value, t.effect) for t in p.tolerations),
-        )
-        before = len(class_vocab)
-        cid = class_vocab.add(sig)
-        if len(class_vocab) > before:
-            class_pods.append(p)
-        class_id[pi] = cid
-    C = max(len(class_vocab), 1)
-    class_affinity = np.ones((C, N), dtype=bool)
-    class_taint = np.ones((C, N), dtype=bool)
-    class_na_score = np.zeros((C, N), dtype=np.float32)
-    class_tt_prefer = np.zeros((C, N), dtype=np.float32)
-    for ci, p in enumerate(class_pods):
-        for ni, n in enumerate(all_nodes):
-            class_affinity[ci, ni] = required_node_affinity_match(
-                n.meta.labels, n.name, p.node_selector, p.node_affinity_required
-            )
-            class_taint[ci, ni] = tolerates_taints(n.taints, p.tolerations)
-            class_na_score[ci, ni] = preferred_node_affinity_score(
-                n.meta.labels, p.node_affinity_preferred
-            )
-            class_tt_prefer[ci, ni] = float(intolerable_prefer_taints(n.taints, p.tolerations))
-    unschedulable = np.array([n.unschedulable for n in all_nodes], dtype=bool)
-
-    # ---- ports ---------------------------------------------------------
-    port_vocab = _Vocab()
-    for p in pods:
-        for hp in p.host_ports():
-            port_vocab.add((hp.host_port, hp.protocol))
-    Pt = max(len(port_vocab), 1)
-    ports = np.zeros((len(pods), Pt), dtype=bool)
-    for pi, p in enumerate(pods):
-        for hp in p.host_ports():
-            ports[pi, port_vocab.index[(hp.host_port, hp.protocol)]] = True
-
-    # ---- per-pod basics ------------------------------------------------
-    P = len(pods)
-    req = np.zeros((P, R), dtype=np.float32)
-    forced = np.full(P, -1, dtype=np.int64)
-    gpu_mem = np.zeros(P, dtype=np.float32)
-    gpu_cnt = np.zeros(P, dtype=np.float32)
-    G = max(1, min(opts.max_gpus_per_node, 64))
-    # per-device multiplicities: a pinned "0-0-1" packs two of the pod's
-    # GPUs onto device 0 (AllocateGpuId's two-pointer can do the same)
-    gpu_forced = np.zeros((P, G), dtype=np.int32)
-    gpu_has_forced = np.zeros(P, dtype=bool)
-    for pi, p in enumerate(pods):
-        for r, v in p.requests().items():
-            if r in res_idx:
-                req[pi, res_idx[r]] = float(v)
-        if p.node_name:
-            forced[pi] = node_index.get(p.node_name, -2)  # -2: unknown node -> fails
-        mem, cnt = p.gpu_request()
-        gpu_mem[pi] = float(mem)
-        gpu_cnt[pi] = float(cnt)
-        idx_anno = p.meta.annotations.get(k8s.ANNO_GPU_INDEX, "")
-        if idx_anno:
-            gpu_has_forced[pi] = True
-            for tok in str(idx_anno).split("-"):
-                if tok.isdigit() and int(tok) < G:
-                    gpu_forced[pi, int(tok)] += 1
-                elif tok.isdigit():
-                    # the reference logs invalid device ids too
-                    # (gpunodeinfo.go:252 "has invalid GPU ID in Annotation")
-                    _log.warning(
-                        "pod %s: gpu-index token %r outside encoded device "
-                        "range [0, %d); its memory debit is dropped — raise "
-                        "EncodeOptions.max_gpus_per_node to cover it",
-                        p.meta.name, tok, G,
-                    )
+        # Domain encoding for non-hostname keys.
+        domain_vals: List[Dict[str, int]] = [dict() for _ in range(K1)]
+        topo_val = np.zeros((K1, N), dtype=np.int64)
+        has_key = np.zeros((K, N), dtype=np.float32)
+        for i, n in enumerate(all_nodes):
+            labels = n.meta.labels
+            has_key[0, i] = 1.0  # hostname: every node is its own domain
+            for kid in range(1, K):
+                key = topo_vocab.items[kid]
+                if key in labels:
+                    has_key[kid, i] = 1.0
+                    dv = domain_vals[kid - 1]
+                    val = labels[key]
+                    if val not in dv:
+                        dv[val] = len(dv)
+                    topo_val[kid - 1, i] = dv[val]
                 else:
-                    _log.warning(
-                        "pod %s: malformed gpu-index token %r (not a device "
-                        "id); its memory debit is dropped",
-                        p.meta.name, tok,
-                    )
+                    topo_val[kid - 1, i] = -1
+        D = max(opts.min_domain_pad, max((len(d) for d in domain_vals), default=1), 1)
+        topo_onehot = np.zeros((K1, N, D), dtype=np.float32)
+        for kk in range(K1):
+            for i in range(N):
+                v = topo_val[kk, i]
+                if v >= 0:
+                    topo_onehot[kk, i, v] = 1.0
 
-    # ---- gpu node arrays ----------------------------------------------
-    gpu_count = np.zeros(N, dtype=np.float32)
-    gpu_cap_mem = np.zeros(N, dtype=np.float32)
-    gpu_slot = np.zeros((N, G), dtype=np.float32)
-    for i, n in enumerate(all_nodes):
-        cnt, per_mem = n.gpu_info()
-        cnt = min(cnt, G)
-        gpu_count[i] = float(cnt)
-        gpu_cap_mem[i] = float(per_mem)
-        gpu_slot[i, :cnt] = 1.0
+    with span("encode.groups"):
+        # ---- selector-group membership ------------------------------------
+        # Memoized per distinct (labels, namespace, owner): workload replicas
+        # share identity, so 50k pods usually mean only dozens of distinct rows.
+        match_groups = np.zeros((len(pods), S), dtype=bool)
+        _row_cache: Dict[tuple, np.ndarray] = {}
+        for pi, p in enumerate(pods):
+            cache_key = (
+                tuple(sorted(p.meta.labels.items())), p.meta.namespace,
+                p.meta.owner_kind, p.meta.owner_name,
+            )
+            row = _row_cache.get(cache_key)
+            if row is None:
+                row = np.zeros(S, dtype=bool)
+                for gid, (sel, namespaces) in enumerate(group_sel):
+                    if sel == "__owner__":
+                        ns, kind, name = namespaces
+                        row[gid] = (
+                            p.meta.namespace == ns
+                            and p.meta.owner_kind == kind
+                            and p.meta.owner_name == name
+                        )
+                    elif p.meta.namespace in namespaces and labels_match_selector(p.meta.labels, sel):
+                        row[gid] = True
+                _row_cache[cache_key] = row
+            match_groups[pi] = row
 
-    # ---- open-local exact storage arrays ------------------------------
-    from open_simulator_tpu.k8s.local_storage import (
-        node_storage_layout,
-        pod_storage_volumes,
-    )
+        # ---- anti-affinity term registry ----------------------------------
+        term_key_arr = np.zeros(T, dtype=np.int64)
+        for (gid, kid), tid in term_vocab.index.items():
+            term_key_arr[tid] = kid
+        own_terms = np.zeros((len(pods), T), dtype=bool)
+        hit_terms = np.zeros((len(pods), T), dtype=bool)
+        for pi in range(len(pods)):
+            for gid, kid in pod_anti_terms[pi]:
+                own_terms[pi, term_vocab.index[(gid, kid)]] = True
+        for (gid, kid), tid in term_vocab.index.items():
+            hit_terms[:, tid] = match_groups[:, gid]
+        match_gid = slot_indices(match_groups)
+        own_tid = slot_indices(own_terms)
+        hit_tid = slot_indices(hit_terms)
 
-    node_layouts = [node_storage_layout(n) for n in all_nodes]
-    pod_vols = [pod_storage_volumes(p) for p in pods]
-    V = max([len(vgs) for vgs, _ in node_layouts] + [1])
-    E = max([len(devs) for _, devs in node_layouts] + [1])
-    Lv = max([len(lvm) for lvm, _ in pod_vols] + [0])
-    Ev = max([len(d) for _, d in pod_vols] + [0])
-    vg_cap = np.zeros((N, V), dtype=np.float32)
-    sdev_cap = np.zeros((N, E), dtype=np.float32)
-    sdev_ssd = np.zeros((N, E), dtype=bool)
-    for i, (vgs, devs) in enumerate(node_layouts):
-        for j, cap in enumerate(vgs[:V]):
-            vg_cap[i, j] = float(cap)
-        for j, (cap, is_ssd) in enumerate(devs[:E]):
-            sdev_cap[i, j] = float(cap)
-            sdev_ssd[i, j] = is_ssd
-    lvm_req = np.zeros((P, max(Lv, 1)), dtype=np.float32)
-    sdev_req = np.zeros((P, max(Ev, 1)), dtype=np.float32)
-    sdev_req_ssd = np.zeros((P, max(Ev, 1)), dtype=bool)
-    for pi, (lvm, devs) in enumerate(pod_vols):
-        for j, size in enumerate(lvm):
-            lvm_req[pi, j] = float(size)
-        for j, (size, wants_ssd) in enumerate(devs):
-            sdev_req[pi, j] = float(size)
-            sdev_req_ssd[pi, j] = wants_ssd
+        # ---- preferred-term registry (existing-pods scoring direction) ----
+        T2 = max(len(pref_term_vocab), 1)
+        pref_term_key_arr = np.zeros(T2, dtype=np.int64)
+        for (gid, kid), tid in pref_term_vocab.index.items():
+            pref_term_key_arr[tid] = kid
+        hit_pref_terms = np.zeros((len(pods), T2), dtype=bool)
+        for (gid, kid), tid in pref_term_vocab.index.items():
+            hit_pref_terms[:, tid] = match_groups[:, gid]
 
-    # ---- VolumeBinding / VolumeZone arrays ----------------------------
-    from open_simulator_tpu.k8s.volumes import analyze_volumes, build_volume_masks
+    with span("encode.classes"):
+        # ---- compat classes ------------------------------------------------
+        class_vocab = _Vocab()
+        class_pods: List[Pod] = []
+        class_id = np.zeros(len(pods), dtype=np.int64)
+        for pi, p in enumerate(pods):
+            sig = (
+                tuple(sorted(p.node_selector.items())),
+                json.dumps(p.node_affinity_required, sort_keys=True) if p.node_affinity_required else "",
+                json.dumps(p.node_affinity_preferred, sort_keys=True) if p.node_affinity_preferred else "",
+                tuple((t.key, t.operator, t.value, t.effect) for t in p.tolerations),
+            )
+            before = len(class_vocab)
+            cid = class_vocab.add(sig)
+            if len(class_vocab) > before:
+                class_pods.append(p)
+            class_id[pi] = cid
+        C = max(len(class_vocab), 1)
+        class_affinity = np.ones((C, N), dtype=bool)
+        class_taint = np.ones((C, N), dtype=bool)
+        class_na_score = np.zeros((C, N), dtype=np.float32)
+        class_tt_prefer = np.zeros((C, N), dtype=np.float32)
+        for ci, p in enumerate(class_pods):
+            for ni, n in enumerate(all_nodes):
+                class_affinity[ci, ni] = required_node_affinity_match(
+                    n.meta.labels, n.name, p.node_selector, p.node_affinity_required
+                )
+                class_taint[ci, ni] = tolerates_taints(n.taints, p.tolerations)
+                class_na_score[ci, ni] = preferred_node_affinity_score(
+                    n.meta.labels, p.node_affinity_preferred
+                )
+                class_tt_prefer[ci, ni] = float(intolerable_prefer_taints(n.taints, p.tolerations))
+        unschedulable = np.array([n.unschedulable for n in all_nodes], dtype=bool)
 
-    vol_model = analyze_volumes(pods, opts.pvcs, opts.pvs, opts.storage_classes)
-    sc_by_name = {s.meta.name: s for s in opts.storage_classes}
-    vol_cid, class_vol_node, class_vol_zone, class_vol_bind, pv_node_ok = (
-        build_volume_masks(vol_model, all_nodes, sc_by_name))
-    n_pv = vol_model.n_pvs
-    Lw = max([len(i.wfc_claim_ids) for i in vol_model.pod_volumes] + [0])
-    Cc = max(len(vol_model.claim_cand), 1)
-    pv_cand = np.zeros((Cc, n_pv), dtype=bool)
-    for ci, row in enumerate(vol_model.claim_cand):
-        pv_cand[ci] = row
-    vol_pv_missing = np.zeros(P, dtype=bool)
-    wfc_ccid = np.zeros((P, Lw), dtype=np.int64)
-    wfc_valid = np.zeros((P, Lw), dtype=bool)
-    # attachable-volume limit keys: vocab over pod demands; a node without
-    # the allocatable key declares no limit (vendored getVolumeLimits only
-    # limits keys the node reports)
-    limit_keys = sorted({lk for i in vol_model.pod_volumes for _, lk in i.limit_claims})
-    Lk = max(len(limit_keys), 1)
-    NO_LIMIT = np.float32(1e9)
-    vol_limit_cap = np.full((N, Lk), NO_LIMIT, dtype=np.float32)
-    for i, n in enumerate(all_nodes):
-        for j, lk in enumerate(limit_keys):
-            if lk in n.allocatable:
-                vol_limit_cap[i, j] = float(n.allocatable[lk])
-    # CSINode driver limits override the legacy allocatable keys (the
-    # vendored CSILimits plugin prefers CSINode, csi.go getVolumeLimits;
-    # real 1.23 clusters publish only CSINode)
-    for cn in opts.csi_nodes:
-        i = node_index.get(cn.meta.name)
-        if i is None:
-            continue
-        for driver, cnt in cn.driver_limits().items():
-            lk = f"attachable-volumes-csi-{driver}"
-            if lk in limit_keys:
-                vol_limit_cap[i, limit_keys.index(lk)] = float(cnt)
-    # unique-volume dedup: a claim mounted by >= 2 pods attaches ONCE per
-    # node (vendored csi/in-tree limits count unique volume names). Shared
-    # claims go to the svol vocabulary + per-pod reference slots; claims
-    # only one pod mounts keep the cheap static per-pod count.
-    claim_lk: Dict[str, str] = {}
-    claim_refs: Dict[str, int] = {}
-    for info in vol_model.pod_volumes:
-        for ck, lk in info.limit_claims:
-            claim_lk[ck] = lk
-            claim_refs[ck] = claim_refs.get(ck, 0) + 1
-    shared_claims = sorted(ck for ck, c in claim_refs.items() if c >= 2)
-    svol_index = {ck: i for i, ck in enumerate(shared_claims)}
-    svol_key = np.array(
-        [limit_keys.index(claim_lk[ck]) for ck in shared_claims], dtype=np.int32)
-    Lv = max(
-        (sum(1 for ck, _ in i.limit_claims if ck in svol_index)
-         for i in vol_model.pod_volumes), default=0)
-    svol_id = np.full((P, Lv), -1, dtype=np.int32)
-    vol_limit_req = np.zeros((P, Lk), dtype=np.float32)
-    for pi, info in enumerate(vol_model.pod_volumes):
-        slot = 0
-        for ck, lk in info.limit_claims:
-            if ck in svol_index:
-                svol_id[pi, slot] = svol_index[ck]
-                slot += 1
-            else:
-                vol_limit_req[pi, limit_keys.index(lk)] += 1.0
-    pre_reasons: Dict[int, str] = {}
-    for pi, info in enumerate(vol_model.pod_volumes):
-        vol_pv_missing[pi] = info.missing_pv
-        for j, cid_w in enumerate(info.wfc_claim_ids[:Lw]):
-            wfc_ccid[pi, j] = cid_w
-            wfc_valid[pi, j] = True
-        if info.pre_reason and forced[pi] == -1:
-            # -4: unschedulable before any node is considered (PreFilter
-            # UnschedulableAndUnresolvable); the engine treats any negative
-            # non--1 forced value as bind-nothing/schedule-nothing. Pods
-            # with a preset nodeName keep their forced binding — real k8s
-            # never re-schedules assigned pods, so a broken volume ref must
-            # not evict them or drop their resource charge.
-            pre_reasons[pi] = info.pre_reason
-            forced[pi] = -4
+    with span("encode.pods"):
+        # ---- ports ---------------------------------------------------------
+        port_vocab = _Vocab()
+        for p in pods:
+            for hp in p.host_ports():
+                port_vocab.add((hp.host_port, hp.protocol))
+        Pt = max(len(port_vocab), 1)
+        ports = np.zeros((len(pods), Pt), dtype=bool)
+        for pi, p in enumerate(pods):
+            for hp in p.host_ports():
+                ports[pi, port_vocab.index[(hp.host_port, hp.protocol)]] = True
 
-    # ---- ragged term arrays -> padded ---------------------------------
-    A = max((len(t) for t in pod_aff_terms), default=0)
-    B = max((len(t) for t in pod_anti_terms), default=0)
-    Cs = max((len(t) for t in pod_spread), default=0)
-    Ap = max((len(t) for t in pod_pref), default=0)
+        # ---- per-pod basics ------------------------------------------------
+        P = len(pods)
+        req = np.zeros((P, R), dtype=np.float32)
+        forced = np.full(P, -1, dtype=np.int64)
+        gpu_mem = np.zeros(P, dtype=np.float32)
+        gpu_cnt = np.zeros(P, dtype=np.float32)
+        G = max(1, min(opts.max_gpus_per_node, 64))
+        # per-device multiplicities: a pinned "0-0-1" packs two of the pod's
+        # GPUs onto device 0 (AllocateGpuId's two-pointer can do the same)
+        gpu_forced = np.zeros((P, G), dtype=np.int32)
+        gpu_has_forced = np.zeros(P, dtype=bool)
+        for pi, p in enumerate(pods):
+            for r, v in p.requests().items():
+                if r in res_idx:
+                    req[pi, res_idx[r]] = float(v)
+            if p.node_name:
+                forced[pi] = node_index.get(p.node_name, -2)  # -2: unknown node -> fails
+            mem, cnt = p.gpu_request()
+            gpu_mem[pi] = float(mem)
+            gpu_cnt[pi] = float(cnt)
+            idx_anno = p.meta.annotations.get(k8s.ANNO_GPU_INDEX, "")
+            if idx_anno:
+                gpu_has_forced[pi] = True
+                for tok in str(idx_anno).split("-"):
+                    if tok.isdigit() and int(tok) < G:
+                        gpu_forced[pi, int(tok)] += 1
+                    elif tok.isdigit():
+                        # the reference logs invalid device ids too
+                        # (gpunodeinfo.go:252 "has invalid GPU ID in Annotation")
+                        _log.warning(
+                            "pod %s: gpu-index token %r outside encoded device "
+                            "range [0, %d); its memory debit is dropped — raise "
+                            "EncodeOptions.max_gpus_per_node to cover it",
+                            p.meta.name, tok, G,
+                        )
+                    else:
+                        _log.warning(
+                            "pod %s: malformed gpu-index token %r (not a device "
+                            "id); its memory debit is dropped",
+                            p.meta.name, tok,
+                        )
 
-    aff_group = _pad2([[t[0] for t in row] for row in pod_aff_terms], A, np.int64(0))
-    aff_key = _pad2([[t[1] for t in row] for row in pod_aff_terms], A, np.int64(0))
-    aff_valid = _pad2([[True for _ in row] for row in pod_aff_terms], A, np.bool_(False))
-    aff_self = _pad2([[t[2] for t in row] for row in pod_aff_terms], A, np.bool_(False))
-    anti_group = _pad2([[t[0] for t in row] for row in pod_anti_terms], B, np.int64(0))
-    anti_key = _pad2([[t[1] for t in row] for row in pod_anti_terms], B, np.int64(0))
-    anti_valid = _pad2([[True for _ in row] for row in pod_anti_terms], B, np.bool_(False))
-    spread_group = _pad2([[t[0] for t in row] for row in pod_spread], Cs, np.int64(0))
-    spread_key = _pad2([[t[1] for t in row] for row in pod_spread], Cs, np.int64(0))
-    spread_skew = _pad2([[t[2] for t in row] for row in pod_spread], Cs, np.float32(1.0))
-    spread_hard = _pad2([[t[3] for t in row] for row in pod_spread], Cs, np.bool_(False))
-    spread_valid = _pad2([[True for _ in row] for row in pod_spread], Cs, np.bool_(False))
-    pref_group = _pad2([[t[0] for t in row] for row in pod_pref], Ap, np.int64(0))
-    pref_key = _pad2([[t[1] for t in row] for row in pod_pref], Ap, np.int64(0))
-    pref_weight = _pad2([[t[2] for t in row] for row in pod_pref], Ap, np.float32(0.0))
-    pref_valid = _pad2([[True for _ in row] for row in pod_pref], Ap, np.bool_(False))
-    pref_tid = _pad2(
-        [[pref_term_vocab.index[(t[0], t[1])] for t in row] for row in pod_pref],
-        Ap, np.int64(0),
-    )
+        # ---- gpu node arrays ----------------------------------------------
+        gpu_count = np.zeros(N, dtype=np.float32)
+        gpu_cap_mem = np.zeros(N, dtype=np.float32)
+        gpu_slot = np.zeros((N, G), dtype=np.float32)
+        for i, n in enumerate(all_nodes):
+            cnt, per_mem = n.gpu_info()
+            cnt = min(cnt, G)
+            gpu_count[i] = float(cnt)
+            gpu_cap_mem[i] = float(per_mem)
+            gpu_slot[i, :cnt] = 1.0
 
-    # distinct node specs: the Simon score depends only on (req, alloc row),
-    # so the per-step [N, R] share computation runs on [U, R] and gathers
-    spec_alloc, spec_inv = np.unique(alloc, axis=0, return_inverse=True)
-    arrays = SnapshotArrays(
-        alloc=alloc,
-        spec_id=spec_inv.reshape(-1).astype(np.int64),
-        spec_alloc=spec_alloc.astype(np.float32),
-        active=active,
-        is_new_node=is_new,
-        topo_onehot=topo_onehot,
-        has_key=has_key,
-        gpu_cap_mem=gpu_cap_mem,
-        gpu_count=gpu_count,
-        gpu_slot=gpu_slot,
-        class_affinity=class_affinity,
-        class_taint=class_taint,
-        class_node_aff_score=class_na_score,
-        class_taint_prefer=class_tt_prefer,
-        unschedulable=unschedulable,
-        req=req,
-        class_id=class_id.astype(np.int32),
-        forced_node=forced.astype(np.int32),
-        ports=ports,
-        match_groups=match_groups,
-        aff_group=aff_group.astype(np.int32),
-        aff_key=aff_key.astype(np.int32),
-        aff_valid=aff_valid,
-        aff_self=aff_self,
-        anti_group=anti_group.astype(np.int32),
-        anti_key=anti_key.astype(np.int32),
-        anti_valid=anti_valid,
-        own_terms=own_terms,
-        hit_terms=hit_terms,
-        match_gid=match_gid,
-        own_tid=own_tid,
-        hit_tid=hit_tid,
-        term_key=term_key_arr.astype(np.int32),
-        spread_group=spread_group.astype(np.int32),
-        spread_key=spread_key.astype(np.int32),
-        spread_skew=spread_skew.astype(np.float32),
-        spread_hard=spread_hard,
-        spread_valid=spread_valid,
-        pref_group=pref_group.astype(np.int32),
-        pref_key=pref_key.astype(np.int32),
-        pref_weight=pref_weight.astype(np.float32),
-        pref_valid=pref_valid,
-        pref_tid=pref_tid.astype(np.int32),
-        pref_term_key=pref_term_key_arr.astype(np.int32),
-        hit_pref=hit_pref_terms,
-        gpu_mem=gpu_mem,
-        gpu_cnt=gpu_cnt,
-        gpu_forced=gpu_forced,
-        gpu_has_forced=gpu_has_forced,
-        vg_cap=vg_cap,
-        sdev_cap=sdev_cap,
-        sdev_ssd=sdev_ssd,
-        lvm_req=lvm_req,
-        sdev_req=sdev_req,
-        sdev_req_ssd=sdev_req_ssd,
-        pv_node_ok=pv_node_ok,
-        pv_cand=pv_cand,
-        vol_cid=vol_cid,
-        class_vol_node=class_vol_node,
-        class_vol_zone=class_vol_zone,
-        class_vol_bind=class_vol_bind,
-        vol_pv_missing=vol_pv_missing,
-        wfc_ccid=wfc_ccid,
-        wfc_valid=wfc_valid,
-        vol_limit_cap=vol_limit_cap,
-        vol_limit_req=vol_limit_req,
-        svol_id=svol_id,
-        svol_key=svol_key,
-    )
+        # ---- open-local exact storage arrays ------------------------------
+        from open_simulator_tpu.k8s.local_storage import (
+            node_storage_layout,
+            pod_storage_volumes,
+        )
+
+        node_layouts = [node_storage_layout(n) for n in all_nodes]
+        pod_vols = [pod_storage_volumes(p) for p in pods]
+        V = max([len(vgs) for vgs, _ in node_layouts] + [1])
+        E = max([len(devs) for _, devs in node_layouts] + [1])
+        Lv = max([len(lvm) for lvm, _ in pod_vols] + [0])
+        Ev = max([len(d) for _, d in pod_vols] + [0])
+        vg_cap = np.zeros((N, V), dtype=np.float32)
+        sdev_cap = np.zeros((N, E), dtype=np.float32)
+        sdev_ssd = np.zeros((N, E), dtype=bool)
+        for i, (vgs, devs) in enumerate(node_layouts):
+            for j, cap in enumerate(vgs[:V]):
+                vg_cap[i, j] = float(cap)
+            for j, (cap, is_ssd) in enumerate(devs[:E]):
+                sdev_cap[i, j] = float(cap)
+                sdev_ssd[i, j] = is_ssd
+        lvm_req = np.zeros((P, max(Lv, 1)), dtype=np.float32)
+        sdev_req = np.zeros((P, max(Ev, 1)), dtype=np.float32)
+        sdev_req_ssd = np.zeros((P, max(Ev, 1)), dtype=bool)
+        for pi, (lvm, devs) in enumerate(pod_vols):
+            for j, size in enumerate(lvm):
+                lvm_req[pi, j] = float(size)
+            for j, (size, wants_ssd) in enumerate(devs):
+                sdev_req[pi, j] = float(size)
+                sdev_req_ssd[pi, j] = wants_ssd
+
+        # ---- VolumeBinding / VolumeZone arrays ----------------------------
+        from open_simulator_tpu.k8s.volumes import analyze_volumes, build_volume_masks
+
+        vol_model = analyze_volumes(pods, opts.pvcs, opts.pvs, opts.storage_classes)
+        sc_by_name = {s.meta.name: s for s in opts.storage_classes}
+        vol_cid, class_vol_node, class_vol_zone, class_vol_bind, pv_node_ok = (
+            build_volume_masks(vol_model, all_nodes, sc_by_name))
+        n_pv = vol_model.n_pvs
+        Lw = max([len(i.wfc_claim_ids) for i in vol_model.pod_volumes] + [0])
+        Cc = max(len(vol_model.claim_cand), 1)
+        pv_cand = np.zeros((Cc, n_pv), dtype=bool)
+        for ci, row in enumerate(vol_model.claim_cand):
+            pv_cand[ci] = row
+        vol_pv_missing = np.zeros(P, dtype=bool)
+        wfc_ccid = np.zeros((P, Lw), dtype=np.int64)
+        wfc_valid = np.zeros((P, Lw), dtype=bool)
+        # attachable-volume limit keys: vocab over pod demands; a node without
+        # the allocatable key declares no limit (vendored getVolumeLimits only
+        # limits keys the node reports)
+        limit_keys = sorted({lk for i in vol_model.pod_volumes for _, lk in i.limit_claims})
+        Lk = max(len(limit_keys), 1)
+        NO_LIMIT = np.float32(1e9)
+        vol_limit_cap = np.full((N, Lk), NO_LIMIT, dtype=np.float32)
+        for i, n in enumerate(all_nodes):
+            for j, lk in enumerate(limit_keys):
+                if lk in n.allocatable:
+                    vol_limit_cap[i, j] = float(n.allocatable[lk])
+        # CSINode driver limits override the legacy allocatable keys (the
+        # vendored CSILimits plugin prefers CSINode, csi.go getVolumeLimits;
+        # real 1.23 clusters publish only CSINode)
+        for cn in opts.csi_nodes:
+            i = node_index.get(cn.meta.name)
+            if i is None:
+                continue
+            for driver, cnt in cn.driver_limits().items():
+                lk = f"attachable-volumes-csi-{driver}"
+                if lk in limit_keys:
+                    vol_limit_cap[i, limit_keys.index(lk)] = float(cnt)
+        # unique-volume dedup: a claim mounted by >= 2 pods attaches ONCE per
+        # node (vendored csi/in-tree limits count unique volume names). Shared
+        # claims go to the svol vocabulary + per-pod reference slots; claims
+        # only one pod mounts keep the cheap static per-pod count.
+        claim_lk: Dict[str, str] = {}
+        claim_refs: Dict[str, int] = {}
+        for info in vol_model.pod_volumes:
+            for ck, lk in info.limit_claims:
+                claim_lk[ck] = lk
+                claim_refs[ck] = claim_refs.get(ck, 0) + 1
+        shared_claims = sorted(ck for ck, c in claim_refs.items() if c >= 2)
+        svol_index = {ck: i for i, ck in enumerate(shared_claims)}
+        svol_key = np.array(
+            [limit_keys.index(claim_lk[ck]) for ck in shared_claims], dtype=np.int32)
+        Lv = max(
+            (sum(1 for ck, _ in i.limit_claims if ck in svol_index)
+             for i in vol_model.pod_volumes), default=0)
+        svol_id = np.full((P, Lv), -1, dtype=np.int32)
+        vol_limit_req = np.zeros((P, Lk), dtype=np.float32)
+        for pi, info in enumerate(vol_model.pod_volumes):
+            slot = 0
+            for ck, lk in info.limit_claims:
+                if ck in svol_index:
+                    svol_id[pi, slot] = svol_index[ck]
+                    slot += 1
+                else:
+                    vol_limit_req[pi, limit_keys.index(lk)] += 1.0
+        pre_reasons: Dict[int, str] = {}
+        for pi, info in enumerate(vol_model.pod_volumes):
+            vol_pv_missing[pi] = info.missing_pv
+            for j, cid_w in enumerate(info.wfc_claim_ids[:Lw]):
+                wfc_ccid[pi, j] = cid_w
+                wfc_valid[pi, j] = True
+            if info.pre_reason and forced[pi] == -1:
+                # -4: unschedulable before any node is considered (PreFilter
+                # UnschedulableAndUnresolvable); the engine treats any negative
+                # non--1 forced value as bind-nothing/schedule-nothing. Pods
+                # with a preset nodeName keep their forced binding — real k8s
+                # never re-schedules assigned pods, so a broken volume ref must
+                # not evict them or drop their resource charge.
+                pre_reasons[pi] = info.pre_reason
+                forced[pi] = -4
+
+    with span("encode.terms"):
+        # ---- ragged term arrays -> padded ---------------------------------
+        A = max((len(t) for t in pod_aff_terms), default=0)
+        B = max((len(t) for t in pod_anti_terms), default=0)
+        Cs = max((len(t) for t in pod_spread), default=0)
+        Ap = max((len(t) for t in pod_pref), default=0)
+
+        aff_group = _pad2([[t[0] for t in row] for row in pod_aff_terms], A, np.int64(0))
+        aff_key = _pad2([[t[1] for t in row] for row in pod_aff_terms], A, np.int64(0))
+        aff_valid = _pad2([[True for _ in row] for row in pod_aff_terms], A, np.bool_(False))
+        aff_self = _pad2([[t[2] for t in row] for row in pod_aff_terms], A, np.bool_(False))
+        anti_group = _pad2([[t[0] for t in row] for row in pod_anti_terms], B, np.int64(0))
+        anti_key = _pad2([[t[1] for t in row] for row in pod_anti_terms], B, np.int64(0))
+        anti_valid = _pad2([[True for _ in row] for row in pod_anti_terms], B, np.bool_(False))
+        spread_group = _pad2([[t[0] for t in row] for row in pod_spread], Cs, np.int64(0))
+        spread_key = _pad2([[t[1] for t in row] for row in pod_spread], Cs, np.int64(0))
+        spread_skew = _pad2([[t[2] for t in row] for row in pod_spread], Cs, np.float32(1.0))
+        spread_hard = _pad2([[t[3] for t in row] for row in pod_spread], Cs, np.bool_(False))
+        spread_valid = _pad2([[True for _ in row] for row in pod_spread], Cs, np.bool_(False))
+        pref_group = _pad2([[t[0] for t in row] for row in pod_pref], Ap, np.int64(0))
+        pref_key = _pad2([[t[1] for t in row] for row in pod_pref], Ap, np.int64(0))
+        pref_weight = _pad2([[t[2] for t in row] for row in pod_pref], Ap, np.float32(0.0))
+        pref_valid = _pad2([[True for _ in row] for row in pod_pref], Ap, np.bool_(False))
+        pref_tid = _pad2(
+            [[pref_term_vocab.index[(t[0], t[1])] for t in row] for row in pod_pref],
+            Ap, np.int64(0),
+        )
+
+        # distinct node specs: the Simon score depends only on (req, alloc row),
+        # so the per-step [N, R] share computation runs on [U, R] and gathers
+        spec_alloc, spec_inv = np.unique(alloc, axis=0, return_inverse=True)
+        arrays = SnapshotArrays(
+            alloc=alloc,
+            spec_id=spec_inv.reshape(-1).astype(np.int64),
+            spec_alloc=spec_alloc.astype(np.float32),
+            active=active,
+            is_new_node=is_new,
+            topo_onehot=topo_onehot,
+            has_key=has_key,
+            gpu_cap_mem=gpu_cap_mem,
+            gpu_count=gpu_count,
+            gpu_slot=gpu_slot,
+            class_affinity=class_affinity,
+            class_taint=class_taint,
+            class_node_aff_score=class_na_score,
+            class_taint_prefer=class_tt_prefer,
+            unschedulable=unschedulable,
+            req=req,
+            class_id=class_id.astype(np.int32),
+            forced_node=forced.astype(np.int32),
+            ports=ports,
+            match_groups=match_groups,
+            aff_group=aff_group.astype(np.int32),
+            aff_key=aff_key.astype(np.int32),
+            aff_valid=aff_valid,
+            aff_self=aff_self,
+            anti_group=anti_group.astype(np.int32),
+            anti_key=anti_key.astype(np.int32),
+            anti_valid=anti_valid,
+            own_terms=own_terms,
+            hit_terms=hit_terms,
+            match_gid=match_gid,
+            own_tid=own_tid,
+            hit_tid=hit_tid,
+            term_key=term_key_arr.astype(np.int32),
+            spread_group=spread_group.astype(np.int32),
+            spread_key=spread_key.astype(np.int32),
+            spread_skew=spread_skew.astype(np.float32),
+            spread_hard=spread_hard,
+            spread_valid=spread_valid,
+            pref_group=pref_group.astype(np.int32),
+            pref_key=pref_key.astype(np.int32),
+            pref_weight=pref_weight.astype(np.float32),
+            pref_valid=pref_valid,
+            pref_tid=pref_tid.astype(np.int32),
+            pref_term_key=pref_term_key_arr.astype(np.int32),
+            hit_pref=hit_pref_terms,
+            gpu_mem=gpu_mem,
+            gpu_cnt=gpu_cnt,
+            gpu_forced=gpu_forced,
+            gpu_has_forced=gpu_has_forced,
+            vg_cap=vg_cap,
+            sdev_cap=sdev_cap,
+            sdev_ssd=sdev_ssd,
+            lvm_req=lvm_req,
+            sdev_req=sdev_req,
+            sdev_req_ssd=sdev_req_ssd,
+            pv_node_ok=pv_node_ok,
+            pv_cand=pv_cand,
+            vol_cid=vol_cid,
+            class_vol_node=class_vol_node,
+            class_vol_zone=class_vol_zone,
+            class_vol_bind=class_vol_bind,
+            vol_pv_missing=vol_pv_missing,
+            wfc_ccid=wfc_ccid,
+            wfc_valid=wfc_valid,
+            vol_limit_cap=vol_limit_cap,
+            vol_limit_req=vol_limit_req,
+            svol_id=svol_id,
+            svol_key=svol_key,
+        )
 
     group_desc = [f"group#{i}" for i in range(S)]
     return ClusterSnapshot(

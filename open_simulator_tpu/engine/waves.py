@@ -534,7 +534,10 @@ def waves_for(arrs, cfg, n_pods_total: Optional[int] = None
         if key in _PLAN_CACHE:
             _PLAN_CACHE.move_to_end(key)
             return _PLAN_CACHE[key]
-    plan = compute_wave_plan(arrs, cfg, n_pods_total=n_pods_total)
+    from open_simulator_tpu.telemetry.spans import span
+
+    with span("wave_plan"):
+        plan = compute_wave_plan(arrs, cfg, n_pods_total=n_pods_total)
     # Degenerate plans map to None so the engine keeps its pre-wave
     # executable — and, critically, its SHARED one: a wave plan is a
     # static jit argument keyed per workload, so "nothing batched but

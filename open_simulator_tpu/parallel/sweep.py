@@ -302,7 +302,8 @@ def capacity_sweep(
     # deadline observed before the batch launches: the exhaustive sweep
     # is one device program, so its only cooperative boundary is here
     lifecycle.check_current("exhaustive sweep start")
-    arrs, _, n_pods = bucketed_device_arrays(snapshot.arrays)
+    with span("sweep.upload"):
+        arrs, _, n_pods = bucketed_device_arrays(snapshot.arrays)
     masks = _padded_lane_masks(
         active_masks_for_counts(snapshot, counts), arrs.alloc.shape[0])
     sweep_cfg = cfg if fail_reasons else cfg._replace(fail_reasons=False)
@@ -315,21 +316,21 @@ def capacity_sweep(
             _execute_sweep(arrs, masks, sweep_cfg, mesh, fail_reasons,
                            retries, backoff_s, isolate_trials, n_pods=n_pods,
                            waves=wave_plan))
-    alloc = np.asarray(arrs.alloc)             # [N, R]
-    cpu_i = snapshot.resources.index("cpu")
-    mem_i = snapshot.resources.index("memory")
-    vg_cap = np.asarray(arrs.vg_cap)           # [N, V]
-    has_storage = bool(np.any(vg_cap > 0))
-
     all_scheduled, cpu_occ, mem_occ, satisfied = [], [], [], []
-    for si in range(len(counts)):
-        st = _lane_stats(
-            alloc, cpu_i, mem_i, vg_cap, has_storage, masks[si], nodes[si],
-            headroom[si], vg_used_arr[si], trial_errors.get(si), thresholds)
-        all_scheduled.append(st.all_scheduled)
-        cpu_occ.append(st.cpu_pct)
-        mem_occ.append(st.mem_pct)
-        satisfied.append(st.satisfied)
+    with span("sweep.lane_stats", lanes=len(counts)):
+        alloc = np.asarray(arrs.alloc)             # [N, R]
+        cpu_i = snapshot.resources.index("cpu")
+        mem_i = snapshot.resources.index("memory")
+        vg_cap = np.asarray(arrs.vg_cap)           # [N, V]
+        has_storage = bool(np.any(vg_cap > 0))
+        for si in range(len(counts)):
+            st = _lane_stats(
+                alloc, cpu_i, mem_i, vg_cap, has_storage, masks[si], nodes[si],
+                headroom[si], vg_used_arr[si], trial_errors.get(si), thresholds)
+            all_scheduled.append(st.all_scheduled)
+            cpu_occ.append(st.cpu_pct)
+            mem_occ.append(st.mem_pct)
+            satisfied.append(st.satisfied)
 
     best = None
     for si in sorted(range(len(counts)), key=lambda i: counts[i]):
@@ -454,7 +455,8 @@ def capacity_bisect(
 
     if max_new < 0:
         raise ValueError(f"max_new must be >= 0, got {max_new}")
-    arrs, _, n_pods = bucketed_device_arrays(snapshot.arrays)
+    with span("sweep.upload"):
+        arrs, _, n_pods = bucketed_device_arrays(snapshot.arrays)
     n_pad = arrs.alloc.shape[0]
     alloc = np.asarray(arrs.alloc)
     cpu_i = snapshot.resources.index("cpu")
@@ -533,15 +535,16 @@ def capacity_bisect(
                 return_state=True, waves=wave_plan)
         carry_holder["carry"] = state
         fresh: Dict[int, dict] = {}
-        for i, c in enumerate(cs):
-            if c in records:
-                continue
-            stats = _lane_stats(alloc, cpu_i, mem_i, vg_cap, has_storage,
-                                masks[i], nodes[i], headroom[i], vg_used[i],
-                                errs.get(i), thresholds)
-            records[c] = fresh[c] = dict(
-                nodes=nodes[i], gpu=gpu[i], vol=vol[i],
-                error=errs.get(i), stats=stats)
+        with span("sweep.lane_stats", lanes=len(new)):
+            for i, c in enumerate(cs):
+                if c in records:
+                    continue
+                stats = _lane_stats(alloc, cpu_i, mem_i, vg_cap, has_storage,
+                                    masks[i], nodes[i], headroom[i], vg_used[i],
+                                    errs.get(i), thresholds)
+                records[c] = fresh[c] = dict(
+                    nodes=nodes[i], gpu=gpu[i], vol=vol[i],
+                    error=errs.get(i), stats=stats)
         if journal is not None and fresh:
             # appended only when the round's outputs are fully hosted: a
             # crash mid-round resumes from the previous complete round
@@ -617,8 +620,6 @@ def _execute_sweep(arrs, masks, sweep_cfg, mesh, fail_reasons,
     the FIRST batched attempt only — retries re-run from fresh buffers
     because the donated ones are already dead. Failed lanes hold neutral
     values (all -1 nodes, pristine headroom)."""
-    import time as _time
-
     from open_simulator_tpu.resilience import faults
 
     if mesh is not None:
@@ -627,36 +628,36 @@ def _execute_sweep(arrs, masks, sweep_cfg, mesh, fail_reasons,
         refuse_node_axis(int(dict(mesh.shape).get("node", 1)))
     from open_simulator_tpu.resilience.retry import run_with_retries
     from open_simulator_tpu.telemetry import registry as _telemetry
+    from open_simulator_tpu.telemetry.spans import span
 
     if n_pods is None:
         n_pods = arrs.req.shape[0]
     trials_total = _telemetry.counter(
         "simon_sweep_trials_total", "capacity-sweep lane outcomes",
         labelnames=("outcome",))
-    trial_seconds = _telemetry.histogram(
-        "simon_sweep_trial_seconds",
-        "wall time of sweep device executions (batched = all lanes at once)",
-        labelnames=("mode",))
 
     def host(out):
-        # lane count from the OUTPUT, not the closure's masks — the
-        # isolated fallback hosts single-lane outputs and must not
-        # allocate a full-batch-shaped zeros block per lane
-        fail = (np.asarray(out.fail_counts)[:, :n_pods] if fail_reasons
-                else np.zeros((out.node.shape[0], n_pods, sweep_cfg.n_ops),
-                              dtype=np.int32))
-        headroom = np.asarray(out.state.headroom)
-        vg_used = np.asarray(out.state.vg_used)
-        # the E_NUMERIC sentinel scan: a NaN escaping a fused score into
-        # the carry must fail the lane loudly, not flow into occupancy
-        # verdicts (on the batched path the isolation fallback then
-        # narrows it to the offending lane)
-        faults.check_finite("batched_schedule", headroom=headroom,
-                            vg_used=vg_used)
-        return (np.asarray(out.node)[:, :n_pods], fail,
-                headroom, vg_used,
-                np.asarray(out.gpu_pick)[:, :n_pods],
-                np.asarray(out.vol_pick)[:, :n_pods])
+        # the device->host copy of the lane outputs and the finite scan,
+        # on both the batched and the isolated-lane path
+        with span("sweep.fetch", lanes=int(out.node.shape[0])):
+            # lane count from the OUTPUT, not the closure's masks — the
+            # isolated fallback hosts single-lane outputs and must not
+            # allocate a full-batch-shaped zeros block per lane
+            fail = (np.asarray(out.fail_counts)[:, :n_pods] if fail_reasons
+                    else np.zeros((out.node.shape[0], n_pods, sweep_cfg.n_ops),
+                                  dtype=np.int32))
+            headroom = np.asarray(out.state.headroom)
+            vg_used = np.asarray(out.state.vg_used)
+            # the E_NUMERIC sentinel scan: a NaN escaping a fused score
+            # into the carry must fail the lane loudly, not flow into
+            # occupancy verdicts (on the batched path the isolation
+            # fallback then narrows it to the offending lane)
+            faults.check_finite("batched_schedule", headroom=headroom,
+                                vg_used=vg_used)
+            return (np.asarray(out.node)[:, :n_pods], fail,
+                    headroom, vg_used,
+                    np.asarray(out.gpu_pick)[:, :n_pods],
+                    np.asarray(out.vol_pick)[:, :n_pods])
 
     carry_once = {"carry": carry}
 
@@ -677,11 +678,9 @@ def _execute_sweep(arrs, masks, sweep_cfg, mesh, fail_reasons,
                                 mesh=mesh, **kw)
 
     def _run_batch(batched_fn):
-        t0 = _time.perf_counter()
         out = run_with_retries(batched_fn, retries=retries,
                                backoff_s=backoff_s)
-        hosted = host(out)  # np.asarray blocks: the timing covers execution
-        trial_seconds.labels(mode="batched").observe(_time.perf_counter() - t0)
+        hosted = host(out)
         trials_total.labels(outcome="ok").inc(masks.shape[0])
         return hosted + ({}, out.state if return_state else None)
 
@@ -724,7 +723,6 @@ def _execute_sweep(arrs, masks, sweep_cfg, mesh, fail_reasons,
     trial_errors = {}
     for si in range(s):
         try:
-            t0 = _time.perf_counter()
             out_i = run_with_retries(
                 lambda: batched_schedule(arrs, jnp.asarray(masks[si:si + 1]),
                                          sweep_cfg, mesh=None,
@@ -734,8 +732,6 @@ def _execute_sweep(arrs, masks, sweep_cfg, mesh, fail_reasons,
                                             if waves is not None else {})),
                 retries=retries, backoff_s=backoff_s)
             nodes_i, fail_i, hr_i, vg_i, gpu_i, vol_i = host(out_i)
-            trial_seconds.labels(mode="isolated").observe(
-                _time.perf_counter() - t0)
             trials_total.labels(outcome="ok").inc()
             nodes[si], fail[si], headroom[si], vg_used[si] = (
                 nodes_i[0], fail_i[0], hr_i[0], vg_i[0])

@@ -120,8 +120,7 @@ class Simulator:
         from open_simulator_tpu.telemetry.spans import span
 
         opts = with_volume_objects(self._encode_options, self.cluster, self._apps)
-        with span("encode"):
-            snapshot = encode_cluster(self.cluster.nodes, self._pods, opts)
+        snapshot = encode_cluster(self.cluster.nodes, self._pods, opts)
         cfg = make_config(snapshot, **self._overrides)
         with span("transfer"):
             # bucketed padding: each schedule_app() grows the pod sequence

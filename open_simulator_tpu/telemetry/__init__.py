@@ -6,6 +6,7 @@ The unified observability layer (ARCHITECTURE.md §8):
                text exposition (GET /metrics renders the default REGISTRY)
   spans.py     nested host-side phase spans -> simon_phase_seconds +
                Chrome-trace JSON export (--trace-out, loads in Perfetto)
+               + simon.<name> events in an active jax.profiler trace
   context.py   causal request tracing (ARCHITECTURE.md §20): the
                X-Simon-Trace-Id contextvar + the always-on black-box
                event ring behind GET /api/trace/<id> and

@@ -63,8 +63,10 @@ def schedule_phase(jit_fn, fn_name: str = "schedule_pods") -> Iterator[None]:
     opens the "schedule" span, diffs jit_fn's compile cache across the
     body to count hit/miss, and on a miss stamps a synthetic "compile"
     span nested inside (epsilon-shrunk so Perfetto's containment nesting
-    is unambiguous). The body must block on the device result
-    (np.asarray) so the span covers real execution."""
+    is unambiguous). That "compile" record goes to the recorder only: a
+    profiler event cannot be emitted after the fact, so a profiler trace
+    shows simon.schedule without it. The body must block on the device
+    result (np.asarray) so the span covers real execution."""
     from open_simulator_tpu.telemetry.spans import RECORDER, span
 
     before = jit_cache_size(jit_fn)

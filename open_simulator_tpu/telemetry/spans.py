@@ -10,16 +10,20 @@ appends a record to a bounded process-wide recorder, which
 microseconds, nested by containment per thread). `--trace-out` on the CLI
 writes that file after a run.
 
-Spans are host-only and nest via a thread-local stack; the per-span cost
-is two `perf_counter` reads and a deque append, so wrapping millisecond
-phases is safe. `jax.profiler` (utils/trace.profile_to) remains the tool
-for *device* timelines; these spans are the host-side complement that
-needs no TensorBoard.
+Spans are host-only and nest via a thread-local stack. Each also opens a
+`jax.profiler.TraceAnnotation` named `simon.<name>`, with its attrs as
+stats, for its own extent: whenever a profiler session is active (a
+`jax.profiler.trace`, `/debug/profile`), the program's phases sit on the
+device trace's own clock beside the device operations. With no session
+active that costs well under a microsecond, so wrapping millisecond
+phases is safe. A span never opens inside traced code (jit, scan, vmap):
+it would time tracing, not execution (graftlint GL4).
 """
 
 from __future__ import annotations
 
 import contextlib
+import functools
 import json
 import os
 import threading
@@ -173,12 +177,25 @@ class SpanRecorder:
 RECORDER = SpanRecorder()
 
 
+@functools.lru_cache(maxsize=None)
+def _profiler_annotation():
+    """jax.profiler.TraceAnnotation, imported on the first span; a null
+    context where jax cannot be imported."""
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        return lambda name, **attrs: contextlib.nullcontext()
+    return TraceAnnotation
+
+
 @contextlib.contextmanager
 def span(name: str, recorder: Optional[SpanRecorder] = None,
          **attrs: str) -> Iterator[Dict[str, float]]:
     """Time a phase: nested spans build the timeline, every exit observes
-    simon_phase_seconds{phase=name}. Exceptions propagate; the span still
-    closes (a failed phase is still a timed phase).
+    simon_phase_seconds{phase=name}, and a profiler session, when one is
+    active, records the event simon.<name> with `attrs` as its stats.
+    Exceptions propagate; the span still closes (a failed phase is still
+    a timed phase).
 
     Yields a dict filled with the span's exact {"t0", "dur"} on exit, so
     a caller that must append sibling/child records after the fact (the
@@ -186,13 +203,15 @@ def span(name: str, recorder: Optional[SpanRecorder] = None,
     recorded interval instead of re-measuring around the context manager
     (which would strictly enclose it and break containment nesting)."""
     rec = recorder or RECORDER
+    annotation = _profiler_annotation()(f"simon.{name}", **attrs)
     stack = rec._stack()
     depth = len(stack)
     stack.append(name)
     info: Dict[str, float] = {}
     t0 = time.perf_counter()
     try:
-        yield info
+        with annotation:
+            yield info
     finally:
         dur = time.perf_counter() - t0
         info["t0"] = t0

@@ -1,6 +1,6 @@
 """Telemetry layer: registry + Prometheus rendering, spans + Chrome
-trace, utils/trace coverage, stack instrumentation, and the REST
-/metrics + /api/explain surfaces."""
+trace, stack instrumentation, and the REST /metrics + /api/explain
+surfaces."""
 
 import json
 import logging
@@ -170,53 +170,6 @@ def test_recorder_clear_and_bound():
     assert len(rec.records()) == 4
     rec.clear()
     assert rec.records() == []
-
-
-# ---- utils/trace.py (previously untested) --------------------------------
-
-
-def test_trace_warn_branch(caplog):
-    from open_simulator_tpu.utils.trace import Trace
-
-    t = Trace("Simulate", warn_after_s=0.0)  # always trips the alarm
-    with t.step("encode"):
-        pass
-    with caplog.at_level(logging.WARNING, logger="simon-tpu.trace"):
-        total = t.finish()
-    assert total >= 0
-    [rec] = [r for r in caplog.records if r.name == "simon-tpu.trace"]
-    assert "Simulate took" in rec.getMessage()
-    assert "encode:" in rec.getMessage()
-
-
-def test_trace_quiet_branch_logs_debug_only(caplog):
-    from open_simulator_tpu.utils.trace import Trace
-
-    t = Trace("Fast", warn_after_s=3600.0)
-    with t.step("s"):
-        pass
-    with caplog.at_level(logging.DEBUG, logger="simon-tpu.trace"):
-        t.finish()
-    [rec] = [r for r in caplog.records if r.name == "simon-tpu.trace"]
-    assert rec.levelno == logging.DEBUG
-
-
-def test_trace_steps_feed_span_recorder():
-    from open_simulator_tpu.telemetry.spans import RECORDER
-    from open_simulator_tpu.utils.trace import Trace
-
-    t = Trace("Wired", warn_after_s=3600.0)
-    with t.step("phase-x"):
-        pass
-    assert any(r.name == "phase-x" for r in RECORDER.records())
-
-
-def test_profile_to_noop_without_dir():
-    from open_simulator_tpu.utils.trace import profile_to
-
-    with profile_to(None):  # must not import jax.profiler or raise
-        marker = True
-    assert marker
 
 
 # ---- engine/sched_config rename ------------------------------------------

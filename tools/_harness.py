@@ -1,8 +1,5 @@
-"""Shared argv parsing + jit-harness setup for the scratch tools.
-
-Both tools/hlo_inventory.py and tools/profile_rich.py drive the same
-north-star-shaped vmapped scan jit; this module keeps their flag
-handling and snapshot/compile setup from drifting apart.
+"""Argv parsing + jit-harness setup for tools/hlo_inventory.py, which
+drives the north-star-shaped vmapped scan jit.
 """
 import argparse
 
