@@ -13,9 +13,9 @@ extension point is tensor-shaped: an ExtensionOp contributes
                                           node ranking; `normalize` picks
                                           the framework NormalizeScore
                                           treatment ("none" | "minmax" |
-                                          "max"), riding the engine's
-                                          single per-step variadic
-                                          reduction.
+                                          "max"), reduced with the
+                                          engine's other normalizers
+                                          each step.
 
 Arguments mirror what the built-in ops see: `state` is the SimState carry,
 `arrs` the device SnapshotArrays, `x` the per-pod slice (engine/scheduler

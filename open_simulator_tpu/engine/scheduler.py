@@ -1075,7 +1075,7 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
         fail_counts = jnp.zeros((0,), jnp.int32)
 
     # ---- scores (feasible nodes only) ---------------------------------
-    # Every normalizer's min/max rides ONE variadic min-reduction (maxes
+    # Every normalizer's min/max is a min-reduction of a masked row (maxes
     # via negation); any-feasible falls out of the selectHost max below.
     # Values are identical to the standalone minmax_normalize/
     # max_normalize formulas.
@@ -1185,15 +1185,13 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
         else:
             ext_scores.append((ext, raw_e, None, None))
 
-    # variadic reduce: one fused pass, no stacked [Q, N] materialization (a
-    # jnp.stack would write ~Q*N floats to HBM per step just to read them
-    # back in the reduce)
-    if red_rows:
-        reds = jax.lax.reduce(
-            tuple(red_rows), tuple(jnp.float32(big) for _ in red_rows),
-            lambda a, b: tuple(jnp.minimum(x, y) for x, y in zip(a, b)),
-            (0,),
-        )
+    # one plain min per row: XLA fuses each into the fusion that computes
+    # its masked row, so no row reaches HBM. A single variadic
+    # lax.reduce over all rows did not fuse under the wave step's two
+    # vmaps: at 64 lanes x 32 pods it wrote every [lanes, pods, N] row
+    # to HBM and relaid it out lane-minor before reducing (71% of a
+    # pools5k.sweep64 launch on v5e; tests/test_chip_compile.py guards)
+    reds = [jnp.min(r) for r in red_rows]
 
     # weights multiply through exact.mul: a traced (or non-power-of-two)
     # weight makes an inexact product, and the CPU would fuse it into +=

@@ -157,9 +157,9 @@ def simon_max_share_score(alloc: jnp.ndarray, req_p: jnp.ndarray, feasible: jnp.
 
 
 # ---- "from-reduced" normalizers ---------------------------------------
-# The scan engine computes every normalizer's min/max in ONE variadic
-# reduction per step; these helpers apply the normalize formulas given the
-# already-reduced lo/hi scalars. Two deliberate hot-path transforms vs the
+# The scan engine reduces every normalizer's min/max per step (one min
+# over each masked row); these helpers apply the normalize formulas given
+# the already-reduced lo/hi scalars. Two deliberate hot-path transforms vs the
 # standalone functions (both argmax-preserving):
 #   * wide divide -> scalar reciprocal + wide multiply (x*100/rng and
 #     x*(100/rng) differ at the ulp level; equal raws still map to equal

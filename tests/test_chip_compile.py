@@ -45,9 +45,10 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _lane_program(n_nodes, n_pods, max_new, lanes):
+def _lane_program(n_nodes, n_pods, max_new, lanes, rich=True, pools=0):
     """The sweep's batched executable inputs, as the product builds them:
-    bucketed all-ops arrays, its wave plan, a zeros carry batch."""
+    bucketed arrays (all ops by default), its wave plan, a zeros carry
+    batch."""
     import jax
 
     from open_simulator_tpu.engine import exec_cache
@@ -55,7 +56,8 @@ def _lane_program(n_nodes, n_pods, max_new, lanes):
     from open_simulator_tpu.engine.waves import waves_for
     from open_simulator_tpu.testing.synthetic import synthetic_snapshot
 
-    snap = synthetic_snapshot(n_nodes, n_pods, max_new=max_new, rich=True)
+    snap = synthetic_snapshot(n_nodes, n_pods, max_new=max_new, rich=rich,
+                              pools=pools)
     cfg = make_config(snap)._replace(fail_reasons=False)
     arrs = exec_cache.pad_snapshot_arrays(
         snap.arrays, *exec_cache.bucket_shape(snap.n_nodes, snap.n_pods))
@@ -63,7 +65,7 @@ def _lane_program(n_nodes, n_pods, max_new, lanes):
     carry = jax.eval_shape(
         lambda a: exec_cache._zeros_carry_batch(a, cfg, lanes), arrs)
     fn = exec_cache.batched_lane_fn(cfg, waves, False)
-    return fn, arrs, carry, (lanes, arrs.alloc.shape[0])
+    return fn, arrs, carry, (lanes, arrs.alloc.shape[0]), waves
 
 
 def _shape(x, sharding):
@@ -76,12 +78,17 @@ def _shape(x, sharding):
 def single_chip_default(topo, no_persistent_cache):
     """The `default` preset's executable (1,024 nodes x 2,048 all-ops
     pods x 256 lanes) compiled for one v5e chip."""
-    import jax
-    import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     one = SingleDeviceSharding(topo.devices[0])
-    fn, arrs, carry, mask_shape = _lane_program(1024, 2048, 8, 256)
+    fn, arrs, carry, mask_shape, _ = _lane_program(1024, 2048, 8, 256)
+    return _compile_one_chip(one, fn, arrs, carry, mask_shape)
+
+
+def _compile_one_chip(one, fn, arrs, carry, mask_shape):
+    import jax
+    import jax.numpy as jnp
+
     tree = jax.tree_util.tree_map
     return jax.jit(fn, donate_argnums=(2,)).lower(
         tree(lambda x: _shape(x, one), arrs),
@@ -107,7 +114,7 @@ def test_mesh_executable_compiles_on_2x2(topo, no_persistent_cache):
     from open_simulator_tpu.engine.exec_cache import mesh_shardings
 
     mesh = Mesh(np.asarray(topo.devices).reshape(4, 1), ("scenario", "node"))
-    fn, arrs, carry, mask_shape = _lane_program(32, 128, 8, 8)
+    fn, arrs, carry, mask_shape, _ = _lane_program(32, 128, 8, 8)
     (arrs_sh, mask_sh, carry_sh, _), out_sh = mesh_shardings(arrs, carry, mesh)
     tree = jax.tree_util.tree_map
     compiled = jax.jit(
@@ -132,3 +139,53 @@ def test_scan_dots_run_at_highest_precision(single_chip_default):
     loose = [d[:160] for d in dots
              if "operand_precision={highest,highest}" not in d]
     assert not loose, loose
+
+
+def _computations(hlo):
+    """{name: body text} of every computation in an HLO module's text."""
+    comps, name, lines = {}, None, []
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            name, lines = head.group(1), []
+        elif line == "}" and name is not None:
+            comps[name] = "\n".join(lines)
+            name = None
+        elif name is not None:
+            lines.append(line)
+    return comps
+
+
+def test_wave_step_reduces_normalizers_without_relayout(topo,
+                                                        no_persistent_cache):
+    """A GRID wave step at 64 lanes (32 tenant pools, waves of 32 pods,
+    a 128-node bucket: the smallest cluster at which the score
+    normalizers once took the unfused road) reduces each normalizer row
+    where the row is computed. No copy in the wave loop's body may
+    relay out a [lanes, wave width, N] f32 row for a reduce: that road
+    wrote and relaid out four such rows every wave, 71% of a 5,120-node
+    64-lane sweep's device time on v5e."""
+    from jax.sharding import SingleDeviceSharding
+
+    from open_simulator_tpu.engine.waves import GRID
+
+    lanes = 64
+    fn, arrs, carry, mask_shape, waves = _lane_program(
+        64, 128, 8, lanes, rich=False, pools=32)
+    assert waves is not None and waves.segments == ((0, 128, GRID, 32),)
+    n = arrs.alloc.shape[0]
+    hlo = _compile_one_chip(SingleDeviceSharding(topo.devices[0]),
+                            fn, arrs, carry, mask_shape).as_text()
+    comps = _computations(hlo)
+    bodies = [comps[b] for b in re.findall(r"body=%([\w.\-]+)", hlo)]
+    assert len(bodies) == 1, "expected the one GRID wave loop"
+    body = bodies[0]
+    # the normalizer values themselves: one f32 per lane and pod
+    assert re.search(rf"= \(?f32\[{lanes},32\]", body), (
+        "no [lanes, wave] f32 result in the wave body: the guard checks "
+        "nothing")
+    rows = re.findall(
+        rf"%([\w.\-]+) = f32\[{lanes},32,{n}\]\{{[^}}]*\}} copy\(", body)
+    reduced = [c for c in rows
+               if re.search(rf" reduce\([^\n]*%{re.escape(c)}[,)]", body)]
+    assert not reduced, reduced

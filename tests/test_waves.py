@@ -375,24 +375,41 @@ def test_equiv_host_ports_across_pools():
     assert plan is not None
 
 
-def test_equiv_sweep_digest(monkeypatch):
-    # the product sweep path: capacity_bisect with waves on vs off must
-    # produce bit-identical plan digests (the acceptance criterion's
-    # ledger-digest form)
-    from open_simulator_tpu.parallel.sweep import capacity_bisect
+@pytest.mark.parametrize("lanes", [4, 64])
+def test_equiv_sweep_digest(monkeypatch, lanes):
+    # the product sweep path with waves on vs off must produce
+    # bit-identical placements and plan digests (the acceptance
+    # criterion's ledger-digest form): a 4-lane capacity_bisect over
+    # waves of 8, and a 64-lane capacity_sweep over GRID waves of 32,
+    # the wave step at the lane count and width of a pools sweep
+    from open_simulator_tpu.parallel.sweep import capacity_bisect, capacity_sweep
     from open_simulator_tpu.telemetry.ledger import plan_digest
 
     monkeypatch.delenv("SIMON_LEDGER_DIR", raising=False)
     monkeypatch.delenv("SIMON_CHECKPOINT_DIR", raising=False)
-    snap = synthetic_snapshot(16, 96, 8, pools=8)
-    digests = {}
+    if lanes == 4:
+        snap = synthetic_snapshot(16, 96, 8, pools=8)
+
+        def run(cfg):
+            return capacity_bisect(snap, cfg, max_new=8, lanes=4)
+    else:
+        snap = synthetic_snapshot(64, 128, 64, pools=32)
+        grid = W.waves_for(snap.arrays, make_config(snap)._replace(
+            fail_reasons=False))
+        assert grid.segments == ((0, 128, W.GRID, 32),)
+
+        def run(cfg):
+            return capacity_sweep(snap, cfg, counts=list(range(lanes)))
+    plans = {}
     for env in ("1", "0"):
         monkeypatch.setenv("SIMON_WAVES", env)
         cfg = make_config(snap)
         assert cfg.wave_scheduling == (env == "1")
-        plan = capacity_bisect(snap, cfg, max_new=8, lanes=4)
-        digests[env] = plan_digest(plan)["digest"]
-    assert digests["1"] == digests["0"]
+        plans[env] = run(cfg)
+    assert np.array_equal(plans["1"].nodes_per_scenario,
+                          plans["0"].nodes_per_scenario)
+    assert (plan_digest(plans["1"])["digest"]
+            == plan_digest(plans["0"])["digest"])
 
 
 def test_simulate_reports_waves():
