@@ -1273,7 +1273,12 @@ def _step(arrs: SnapshotArrays, active: jnp.ndarray, cfg: EngineConfig,
         topk_node = jnp.zeros((0,), jnp.int32)
         topk_score = jnp.zeros((0,), f32)
         topk_parts = jnp.zeros((0, 0), f32)
-    top = jnp.max(masked_score)
+    # one pass writes the masked score row and its max, and the min-index
+    # pass reads that row: where every score input is a small table, XLA
+    # would otherwise fuse the whole score into both reduces and compute
+    # it twice a step (on v5e a wave step's costliest fusion, twice)
+    masked_score, top = jax.lax.optimization_barrier(
+        (masked_score, jnp.max(masked_score)))
     any_feasible = top > neg_inf  # scores are finite; == neg_inf iff mask empty
     sel_node = jnp.min(
         jnp.where(masked_score == top, jax.lax.iota(jnp.int32, n_nodes), n_nodes)

@@ -156,8 +156,38 @@ def _computations(hlo):
     return comps
 
 
-def test_wave_step_reduces_normalizers_without_relayout(topo,
-                                                        no_persistent_cache):
+@pytest.fixture(scope="module")
+def wave_body(topo, no_persistent_cache):
+    """lanes -> (loop body text, node bucket) of a GRID wave step compiled
+    for one v5e: 32 tenant pools, waves of 32 pods, a 128-node bucket."""
+    from jax.sharding import SingleDeviceSharding
+
+    from open_simulator_tpu.engine.waves import GRID
+
+    built = {}
+
+    def get(lanes):
+        if lanes not in built:
+            fn, arrs, carry, mask_shape, waves = _lane_program(
+                64, 128, 8, lanes, rich=False, pools=32)
+            assert waves is not None and waves.segments == ((0, 128, GRID, 32),)
+            hlo = _compile_one_chip(SingleDeviceSharding(topo.devices[0]),
+                                    fn, arrs, carry, mask_shape).as_text()
+            comps = _computations(hlo)
+            bodies = [comps[b] for b in re.findall(r"body=%([\w.\-]+)", hlo)]
+            assert len(bodies) == 1, "expected the one GRID wave loop"
+            body = bodies[0]
+            # one f32 per lane and pod: the normalizers, the best scores
+            assert re.search(rf"= \(?f32\[{lanes},32\]", body), (
+                "no [lanes, wave] f32 result in the wave body: the guard "
+                "checks nothing")
+            built[lanes] = body, arrs.alloc.shape[0]
+        return built[lanes]
+
+    return get
+
+
+def test_wave_step_reduces_normalizers_without_relayout(wave_body):
     """A GRID wave step at 64 lanes (32 tenant pools, waves of 32 pods,
     a 128-node bucket: the smallest cluster at which the score
     normalizers once took the unfused road) reduces each normalizer row
@@ -165,27 +195,42 @@ def test_wave_step_reduces_normalizers_without_relayout(topo,
     relay out a [lanes, wave width, N] f32 row for a reduce: that road
     wrote and relaid out four such rows every wave, 71% of a 5,120-node
     64-lane sweep's device time on v5e."""
-    from jax.sharding import SingleDeviceSharding
-
-    from open_simulator_tpu.engine.waves import GRID
-
     lanes = 64
-    fn, arrs, carry, mask_shape, waves = _lane_program(
-        64, 128, 8, lanes, rich=False, pools=32)
-    assert waves is not None and waves.segments == ((0, 128, GRID, 32),)
-    n = arrs.alloc.shape[0]
-    hlo = _compile_one_chip(SingleDeviceSharding(topo.devices[0]),
-                            fn, arrs, carry, mask_shape).as_text()
-    comps = _computations(hlo)
-    bodies = [comps[b] for b in re.findall(r"body=%([\w.\-]+)", hlo)]
-    assert len(bodies) == 1, "expected the one GRID wave loop"
-    body = bodies[0]
-    # the normalizer values themselves: one f32 per lane and pod
-    assert re.search(rf"= \(?f32\[{lanes},32\]", body), (
-        "no [lanes, wave] f32 result in the wave body: the guard checks "
-        "nothing")
+    body, n = wave_body(lanes)
     rows = re.findall(
         rf"%([\w.\-]+) = f32\[{lanes},32,{n}\]\{{[^}}]*\}} copy\(", body)
     reduced = [c for c in rows
                if re.search(rf" reduce\([^\n]*%{re.escape(c)}[,)]", body)]
     assert not reduced, reduced
+
+
+@pytest.mark.parametrize("lanes", [8, 64])
+def test_wave_step_broadcasts_domains_without_gather(wave_body, lanes):
+    """The spread score reads each node's zone count from a [zones, wave]
+    table per lane. No op in the wave loop's body may gather that table
+    out to an [N, lanes, wave width] f32 row, nor copy such a row to
+    relay it out: on a 5,120-node cluster that gather and its copy took
+    52% of a 64-lane sweep's device time on v5e, and 42% of the wave
+    loop at 8 lanes."""
+    body, n = wave_body(lanes)
+    dims = sorted((n, lanes, 32))
+    rows = [line.strip()[:160] for line in body.splitlines()
+            if (m := re.search(r"= f32\[([\d,]+)\]\S* (\w+)\(", line))
+            and sorted(map(int, m.group(1).split(","))) == dims
+            and (m.group(2) == "copy" or "gather" in line)]
+    assert not rows, rows
+
+
+@pytest.mark.parametrize("lanes", [8, 64])
+def test_wave_step_scores_each_node_once(wave_body, lanes):
+    """selectHost's min-index pass reads the masked score row that the
+    max's pass wrote. When every score input is a small table, XLA would
+    rather fuse the whole score into both reduces and compute it twice
+    a wave: on a 5,120-node cluster at 64 lanes that second score took a
+    third of the wave loop's device time on v5e."""
+    body, n = wave_body(lanes)
+    rows = set(re.findall(rf"%([\w.\-]+) = f32\[{lanes},32,{n}\]\{{", body))
+    picks = re.findall(rf"= s32\[{lanes},32\]\{{[^}}]*\}} fusion\(([^)]*)\)",
+                       body)
+    assert picks, "no [lanes, wave] index fusion: the guard checks nothing"
+    assert any(set(re.findall(r"%([\w.\-]+)", ops)) & rows for ops in picks)

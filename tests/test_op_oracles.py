@@ -5,12 +5,14 @@ vendored scheduler); SURVEY.md section 4 calls for adding these in the
 rebuild — random instances, independently recomputed expectations.
 """
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from open_simulator_tpu.ops import filters, scores
 from open_simulator_tpu.ops.domains import (
+    SELECT_MAX_DOMAINS,
     broadcast_domains,
     domain_count,
     domain_index,
@@ -49,20 +51,25 @@ def test_domain_count_oracle(seed):
     np.testing.assert_allclose(got, want)
 
 
+@pytest.mark.parametrize("d", [6, SELECT_MAX_DOMAINS, SELECT_MAX_DOMAINS + 1, 40])
 @pytest.mark.parametrize("seed", range(4))
-def test_domain_index_gather_equals_onehot_broadcast(seed):
-    """The scan's one-hot broadcasts became gathers by domain id: bit for
-    bit the `O @ v` they replaced (0 where a node lacks the key), for
-    vector and matrix per-domain values."""
+def test_domain_index_gather_equals_onehot_broadcast(seed, d):
+    """The scan's one-hot broadcasts select by domain id (few domains) or
+    gather by it (many): bit for bit the `O @ v` they replaced (0 where a
+    node lacks the key), for vector and matrix per-domain values."""
     rng = np.random.RandomState(seed)
-    n, d, q = 23, 6, 3
+    n, q = 23, 3
     onehot, ids = random_topology(rng, n, d)
+    onehot[0, :2], ids[:2] = 0.0, -1              # nodes without the key
     idx = np.asarray(domain_index(jnp.asarray(onehot)))[0]
     np.testing.assert_array_equal(idx, np.where(ids < 0, d, ids))
-    per_dom = rng.randint(0, 400, size=(d, q)).astype(np.float32)
+    per_dom = (rng.standard_normal((d, q)) * 400).astype(np.float32)
     for v in (per_dom, per_dom[:, 0]):
         got = np.asarray(broadcast_domains(jnp.asarray(v), jnp.asarray(idx)))
+        assert got.dtype == np.float32 and got.shape == (n,) + v.shape[1:]
         np.testing.assert_array_equal(got, onehot[0].astype(np.float64) @ v)
+        jaxpr = str(jax.make_jaxpr(broadcast_domains)(v, idx))
+        assert ("gather" in jaxpr) == (d > SELECT_MAX_DOMAINS), jaxpr
 
 
 @pytest.mark.parametrize("seed", range(5))
