@@ -2,7 +2,7 @@
 # graftlint + the tier-1 verify command from ROADMAP.md plus one chaos
 # scenario end to end (tools/smoke.sh).
 
-.PHONY: test lint smoke bench bench-smoke bench-regress lifecycle-smoke \
+.PHONY: test lint smoke lifecycle-smoke \
 	multichip-smoke campaign-smoke replay-smoke session-smoke serve-smoke \
 	tune-smoke fault-smoke journal-smoke trace-smoke live-smoke
 
@@ -19,20 +19,6 @@ lint:
 smoke:
 	bash tools/smoke.sh
 
-bench:
-	python bench.py
-
-# the driver's bench contract at toy scale: the demo preset must emit one
-# parseable JSON line with value > 0 (BENCH_r01-r05 recorded a TypeError
-# for five rounds because nothing ran bench.py outside the judge)
-bench-smoke:
-	env JAX_PLATFORMS=cpu python bench.py --preset demo --skip-baseline \
-	  | python -c "import json,sys; \
-lines=[l for l in sys.stdin if l.strip().startswith('{')]; \
-d=json.loads(lines[-1]); \
-assert d['value'] > 0, d; \
-print('bench-smoke OK:', d['metric'], d['value'], d['unit'])"
-
 # graceful-drain smoke against a real server process: SIGTERM with one
 # request in flight must flip /readyz (not /healthz), reject new work
 # with 503, finish the held request, and write the final ledger record
@@ -42,9 +28,7 @@ lifecycle-smoke:
 # the 8-device gate (ROADMAP item 1): batched_schedule over a
 # (scenario x node) mesh of 8 virtual CPU devices must produce
 # BIT-IDENTICAL node assignments (ledger digest equality) to the
-# single-device run — incl. the wave-scheduled pools workload. The
-# MULTICHIP_r01-r05 rot (five rounds of a silently recorded crash)
-# cannot recur while this is in smoke.
+# single-device run — incl. the wave-scheduled pools workload.
 multichip-smoke:
 	env XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 	  JAX_PLATFORMS=cpu python tools/multichip_smoke.py
@@ -125,10 +109,3 @@ trace-smoke:
 # `simon-tpu top --once` frame, and end live streams cleanly on SIGTERM
 live-smoke:
 	env JAX_PLATFORMS=cpu python tools/live_smoke.py
-
-# regression gate over the run ledger (SIMON_LEDGER_DIR or
-# BENCH_LEDGER_DIR=... make bench-regress): the newest bench record per
-# shape must stay within the threshold of the trailing median of its
-# priors; exits 0 with a notice when the ledger holds < 2 bench records
-bench-regress:
-	python tools/bench_regress.py --ledger-dir "$${BENCH_LEDGER_DIR:-$${SIMON_LEDGER_DIR:-}}"

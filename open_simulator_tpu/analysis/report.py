@@ -20,7 +20,7 @@ from open_simulator_tpu.analysis.walker import Module, iter_py_files
 # What `simon-tpu lint` checks by default: the product tree. Tests and
 # examples are exercised by pytest itself; fixtures under tests/fixtures/
 # are deliberately-broken lint corpora.
-DEFAULT_PATHS = ("open_simulator_tpu", "tools", "bench.py", "__graft_entry__.py")
+DEFAULT_PATHS = ("open_simulator_tpu", "tools", "__graft_entry__.py")
 
 
 def repo_root() -> str:

@@ -4,7 +4,7 @@ Four cooperating pieces (ARCHITECTURE.md §13):
 
   fleet    discovery — a directory/manifest of recorded cluster dumps
            becomes an ordered list of (name, loader, source digest)
-           entries; synthetic fleet writer for bench/smoke/tests
+           entries; synthetic fleet writer for smoke/tests
   audit    the placement invariant auditor: post-hoc vectorized proof
            that a SimulateResult respects the engine's own contracts
            (bindings on live nodes, consumption within allocatable,

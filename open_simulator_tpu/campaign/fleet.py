@@ -217,7 +217,7 @@ def fleet_digest(entries: Sequence[ClusterEntry], scenario: str,
         json.dumps(body, sort_keys=True).encode()).hexdigest()[:16]
 
 
-# ---- synthetic fleets (bench / smoke / tests) ----------------------------
+# ---- synthetic fleets (smoke / tests) ------------------------------------
 
 
 def write_synthetic_fleet(root: str, n_clusters: int = 3,

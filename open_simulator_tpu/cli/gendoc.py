@@ -15,30 +15,6 @@ def generate_docs(parser: argparse.ArgumentParser, out_dir: str) -> None:
                 _write_cmd(sub, os.path.join(out_dir, f"simon-tpu_{name}.md"))
 
 
-def generate_bench_doc(out_dir: str) -> bool:
-    """Document the repo-root bench.py flags alongside the CLI tree.
-
-    Soft: returns False (writing nothing) when bench.py is not
-    importable — an installed package without the repo checkout has no
-    bench script to document. Importing bench is cheap: its module
-    level is argparse only; jax loads lazily inside the run functions."""
-    import importlib
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    try:
-        bench = importlib.import_module("bench")
-        parser = bench.build_parser()
-    except (ImportError, AttributeError):
-        return False
-    os.makedirs(out_dir, exist_ok=True)
-    _write_cmd(parser, os.path.join(out_dir, "bench.md"))
-    return True
-
-
 def _write_cmd(parser: argparse.ArgumentParser, path: str) -> None:
     lines = [f"## {parser.prog}", "", parser.description or "", "", "```",
              parser.format_help().rstrip(), "```", ""]

@@ -81,11 +81,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "fingerprint is verified, and the final result is "
                          "identical to an uninterrupted run (bisect mode "
                          "only)")
-    ap.add_argument("--no-waves", action="store_true",
-                    help="disable wave scheduling (engine/waves.py): run "
-                         "the pure sequential scan; equivalent to "
-                         "SIMON_WAVES=0 (results are bit-identical either "
-                         "way — this is a perf/debug switch)")
 
     ex = sub.add_parser(
         "explain",
@@ -112,9 +107,6 @@ def build_parser() -> argparse.ArgumentParser:
     ex.add_argument("--json", action="store_true",
                     help="emit the report as JSON")
     ex.add_argument("--output-file", default="")
-    ex.add_argument("--no-waves", action="store_true",
-                    help="disable wave scheduling for this run "
-                         "(SIMON_WAVES=0 equivalent)")
     ex.add_argument("--trace-out", default="",
                     help="write a Chrome-trace JSON timeline of this run's "
                          "phases (open in chrome://tracing or Perfetto)")
@@ -137,9 +129,6 @@ def build_parser() -> argparse.ArgumentParser:
     sp.add_argument("--explain-topk", type=int, default=3,
                     help="candidate nodes recorded per pod during serving "
                          "simulations for GET /api/explain (0 disables)")
-    sp.add_argument("--no-waves", action="store_true",
-                    help="disable wave scheduling for all serving "
-                         "simulations (SIMON_WAVES=0 equivalent)")
     sp.add_argument(
         "--compile-cache-dir", default="",
         help=_CACHE_HELP)
@@ -234,9 +223,6 @@ def build_parser() -> argparse.ArgumentParser:
     ch.add_argument("--drain-node", action=_FaultAction, fault_kind="drain_node",
                     dest="events", metavar="NAME",
                     help="drain this node (repeatable)")
-    ch.add_argument("--no-waves", action="store_true",
-                    help="disable wave scheduling for the chaos re-scans "
-                         "(SIMON_WAVES=0 equivalent)")
     ch.add_argument("--zone-key", default="topology.kubernetes.io/zone",
                     help="node label key that defines zones")
     ch.add_argument("--json", action="store_true", help="emit the report as JSON")
@@ -265,7 +251,7 @@ def build_parser() -> argparse.ArgumentParser:
     rn_sub = rn.add_subparsers(dest="runs_command")
     rn_ls = rn_sub.add_parser("list", help="summarize recorded runs")
     rn_ls.add_argument("--surface", default="",
-                       help="only this surface (apply/chaos/bench/sweep/"
+                       help="only this surface (apply/chaos/sweep/"
                             "simulate/campaign/server:<route>)")
     rn_ls.add_argument("--campaign", default="", metavar="ID",
                        help="only records tagged with this campaign id "
@@ -335,9 +321,6 @@ def build_parser() -> argparse.ArgumentParser:
                              "live in <ledger>/checkpoints")
     cp_run.add_argument("--compile-cache-dir", default="",
                         help=_CACHE_HELP)
-    cp_run.add_argument("--no-waves", action="store_true",
-                        help="disable wave scheduling for every cluster "
-                             "(SIMON_WAVES=0 equivalent)")
     cp_run.add_argument("--json", action="store_true",
                         help="emit the fleet report as JSON")
     cp_run.add_argument("--output-file", default="")
@@ -360,8 +343,6 @@ def build_parser() -> argparse.ArgumentParser:
                         help="recorded API dump file or manifest dir")
     cp_aud.add_argument("--json", action="store_true",
                         help="emit the audit report as JSON")
-    cp_aud.add_argument("--no-waves", action="store_true",
-                        help="disable wave scheduling for the audited run")
     cp_aud.add_argument("--output-file", default="")
 
     rp = sub.add_parser(
@@ -420,9 +401,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "executed step + a trajectory summary (also "
                          "honors SIMON_LEDGER_DIR); checkpoints live in "
                          "<ledger>/checkpoints")
-    rp.add_argument("--no-waves", action="store_true",
-                    help="disable wave scheduling (SIMON_WAVES=0 "
-                         "equivalent)")
     rp.add_argument("--json", action="store_true",
                     help="emit the report as JSON")
     rp.add_argument("--output-file", default="")
@@ -585,9 +563,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "SIMON_LEDGER_DIR)")
     tn.add_argument("--compile-cache-dir", default="",
                     help=_CACHE_HELP)
-    tn.add_argument("--no-waves", action="store_true",
-                    help="accepted for symmetry: tune rounds run the "
-                         "batched scan (no wave plans apply)")
     tn.add_argument("--trace-out", default="",
                     help="write a Chrome-trace JSON timeline of the "
                          "search's phases")
@@ -615,7 +590,7 @@ def build_parser() -> argparse.ArgumentParser:
         "paths", nargs="*", metavar="PATH",
         help="files/dirs to lint, relative to the repo root "
              "(default: the product tree — open_simulator_tpu/, tools/, "
-             "bench.py)")
+             "__graft_entry__.py)")
     lt.add_argument("--format", choices=("text", "json", "sarif"),
                     default="text", help="finding output format")
     lt.add_argument("--select", default="",
@@ -1237,14 +1212,6 @@ def main(argv=None) -> int:
     parser = build_parser()
     args = parser.parse_args(argv)
 
-    if getattr(args, "no_waves", False):
-        # one lever end to end: make_config folds SIMON_WAVES into
-        # EngineConfig.wave_scheduling, so every entry point this process
-        # runs (apply, server routes, chaos, explain) sees the switch
-        from open_simulator_tpu.engine.waves import WAVES_ENV
-
-        os.environ[WAVES_ENV] = "0"
-
     if getattr(args, "ledger_dir", ""):
         # flight recorder: stdlib-only configuration, safe before jax loads
         from open_simulator_tpu.telemetry import ledger
@@ -1495,13 +1462,9 @@ def main(argv=None) -> int:
         return _top_main(args)
 
     if args.command == "gen-doc":
-        from open_simulator_tpu.cli.gendoc import (
-            generate_bench_doc,
-            generate_docs,
-        )
+        from open_simulator_tpu.cli.gendoc import generate_docs
 
         generate_docs(build_parser(), args.dir)
-        generate_bench_doc(args.dir)
         print(f"docs written to {args.dir}")
         return 0
 

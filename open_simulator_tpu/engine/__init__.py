@@ -20,7 +20,6 @@ from open_simulator_tpu.engine.scheduler import (
 )
 from open_simulator_tpu.engine.exec_cache import (
     EXEC_CACHE,
-    BucketPolicy,
     bucket_shape,
     bucketed_device_arrays,
     enable_persistent_cache,

@@ -57,7 +57,6 @@ import os
 import threading
 import time
 from collections import OrderedDict
-from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -72,33 +71,17 @@ from open_simulator_tpu.encode.snapshot import (
 _log = logging.getLogger(__name__)
 
 
-# ---- bucketing policy ---------------------------------------------------
+# ---- bucket boundaries --------------------------------------------------
 
-@dataclass(frozen=True)
-class BucketPolicy:
-    """Round an axis length up to its bucket boundary.
-
-    Power-of-two steps up to `linear_from`, then multiples of
-    `linear_step` (the serving-stack batch-bucketing shape ladder:
-    geometric where relative padding waste is bounded, linear where a
-    doubling would waste half the axis). The defaults keep the tracked
-    north-star shape (5120 nodes x 51200 pods) exactly on a boundary, so
-    the benchmark series stays comparable.
-    """
-
-    enabled: bool = True
-    node_linear_from: int = 1024
-    node_linear_step: int = 1024
-    pod_linear_from: int = 2048
-    pod_linear_step: int = 2048
-
-
-def _default_policy() -> BucketPolicy:
-    # SIMON_BUCKETING=0 opts the whole process out (debug escape hatch)
-    return BucketPolicy(enabled=os.environ.get("SIMON_BUCKETING", "1") != "0")
-
-
-DEFAULT_POLICY = _default_policy()
+# Power-of-two steps up to `*_LINEAR_FROM`, then multiples of
+# `*_LINEAR_STEP` (the serving-stack batch-bucketing shape ladder:
+# geometric where relative padding waste is bounded, linear where a
+# doubling would waste half the axis). 5,120 nodes x 51,200 pods sits
+# exactly on a boundary.
+NODE_LINEAR_FROM = 1024
+NODE_LINEAR_STEP = 1024
+POD_LINEAR_FROM = 2048
+POD_LINEAR_STEP = 2048
 
 
 def bucket_dim(n: int, linear_from: int, linear_step: int) -> int:
@@ -113,13 +96,9 @@ def bucket_dim(n: int, linear_from: int, linear_step: int) -> int:
     return -(-n // linear_step) * linear_step
 
 
-def bucket_shape(n_nodes: int, n_pods: int,
-                 policy: Optional[BucketPolicy] = None) -> Tuple[int, int]:
-    p = policy or DEFAULT_POLICY
-    if not p.enabled:
-        return n_nodes, n_pods
-    return (bucket_dim(n_nodes, p.node_linear_from, p.node_linear_step),
-            bucket_dim(n_pods, p.pod_linear_from, p.pod_linear_step))
+def bucket_shape(n_nodes: int, n_pods: int) -> Tuple[int, int]:
+    return (bucket_dim(n_nodes, NODE_LINEAR_FROM, NODE_LINEAR_STEP),
+            bucket_dim(n_pods, POD_LINEAR_FROM, POD_LINEAR_STEP))
 
 
 # ---- SnapshotArrays padding --------------------------------------------
@@ -184,8 +163,7 @@ def pad_snapshot_arrays(arrs: SnapshotArrays, n_nodes_to: int,
     return type(arrs)(**out)
 
 
-def bucketed_device_arrays(arrs: SnapshotArrays,
-                           policy: Optional[BucketPolicy] = None):
+def bucketed_device_arrays(arrs: SnapshotArrays):
     """Pad to the bucket and transfer to the default device in one hop.
     Returns (device_arrays, n_nodes_orig, n_pods_orig) — the originals
     are what `unpad_output` and host-side decode need back."""
@@ -193,7 +171,7 @@ def bucketed_device_arrays(arrs: SnapshotArrays,
     import jax.numpy as jnp
 
     n, p = arrs.alloc.shape[0], arrs.req.shape[0]
-    nb, pb = bucket_shape(n, p, policy)
+    nb, pb = bucket_shape(n, p)
     padded = pad_snapshot_arrays(arrs, nb, pb)
     return jax.tree_util.tree_map(jnp.asarray, padded), n, p
 
@@ -442,7 +420,7 @@ class ExecutableCache:
         """Per-fn cost summary ({fn: {flops, bytes_accessed,
         peak_hbm_bytes, compile_s, entries}}; the newest entry's profile
         wins when a fn holds several shapes). Feeds the gauges, the
-        ledger cost section, and bench JSON lines."""
+        ledger cost section, and `/debug/executables`."""
         with self._lock:
             costs = [dict(c) for c in self._costs.values()]
         out: Dict[str, Dict[str, Any]] = {}
@@ -927,7 +905,7 @@ _persistent_dir: Optional[str] = None
 
 def enable_persistent_cache(path: str = "") -> str:
     """Place jax's on-disk compilation cache; every entry point (CLI
-    ``main``, the server, ``bench.py``, ``chip_smoke.py``) calls this
+    ``main``, the server, ``benchmark/``, ``chip_smoke.py``) calls this
     once, before its first compile. ``JAX_COMPILATION_CACHE_DIR``, when
     set, IS the cache and no flag overrides it; otherwise ``path`` (the
     ``--compile-cache-dir`` flag), else ``DEFAULT_CACHE_DIR``. Returns

@@ -165,15 +165,6 @@ class EngineConfig(NamedTuple):
     # carry no gpu/storage/WFC-volume claims (those picks are
     # order-dependent within the prefix) and no extensions are registered.
     forced_prefix: int = 0
-    # Wave scheduling (engine/waves.py): entry points partition the pod
-    # sequence into carry-independent waves and hand schedule_pods a
-    # static WavePlan; provably-independent runs execute as one batched
-    # filter+score + one carry merge instead of one scan step per pod.
-    # Results are bit-identical to scan order (the planner only batches
-    # what it can prove). Default on; SIMON_WAVES=0 is the process-wide
-    # escape hatch (make_config folds it in here so the ledger
-    # fingerprint records which mode ran).
-    wave_scheduling: bool = True
     # Traced score weights (tune/): the K WEIGHT_FIELDS become a traced
     # [K] input of the step instead of compile-time constants, so W
     # policy variants run as lanes of ONE executable. Enable flags stay
@@ -1705,15 +1696,10 @@ def make_config(snapshot: ClusterSnapshot, **overrides) -> EngineConfig:
     enable_storage = bool(
         np.any(snapshot.arrays.vg_cap > 0) or np.any(snapshot.arrays.sdev_cap > 0)
     )
-    from open_simulator_tpu.engine.waves import waves_enabled
-
     a = snapshot.arrays
     kw: Dict[str, Any] = dict(
         n_resources=len(res), cpu_mem_idx=cpu_mem, enable_gpu=enable_gpu,
         enable_storage=enable_storage,
-        # SIMON_WAVES=0 escape hatch folded into the config so the run
-        # fingerprint records which engine mode answered
-        wave_scheduling=waves_enabled(),
         compact_carry=max_per_node < 255,
         # feature gates: compile out ops whose inputs are empty across the
         # whole pod sequence (results identical; see EngineConfig docs)

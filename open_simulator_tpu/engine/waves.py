@@ -66,7 +66,6 @@ graftlint resolver discipline. Nothing here runs inside jit scope.
 from __future__ import annotations
 
 import logging
-import os
 import threading
 import weakref
 from collections import OrderedDict
@@ -75,8 +74,6 @@ from typing import Dict, NamedTuple, Optional, Tuple
 import numpy as np
 
 _log = logging.getLogger(__name__)
-
-WAVES_ENV = "SIMON_WAVES"
 
 # segment kinds (WavePlan.segments[i][2])
 SCAN = 0      # sequential lax.scan over the slice (the fallback path)
@@ -189,12 +186,6 @@ class WavePlan(NamedTuple):
                 batched[lo:hi] = True
                 wid += 1
         return wave_id, batched
-
-
-def waves_enabled() -> bool:
-    """The process-wide escape hatch: SIMON_WAVES=0 disables wave
-    scheduling everywhere regardless of EngineConfig."""
-    return os.environ.get(WAVES_ENV, "1") != "0"
 
 
 def _slot_union(out: np.ndarray, idx: np.ndarray, valid: np.ndarray) -> None:
@@ -519,11 +510,9 @@ _plan_lock = threading.Lock()
 def waves_for(arrs, cfg, n_pods_total: Optional[int] = None
               ) -> Optional[WavePlan]:
     """The product entry point: plan for (host snapshot arrays, config),
-    or None when wave scheduling is off / the analysis found nothing to
-    batch (the engine then keeps its exact pre-wave executable and cache
-    key). Plans are cached by workload digest."""
-    if not cfg.wave_scheduling or not waves_enabled():
-        return None
+    or None when the analysis found nothing to batch (the engine then
+    keeps its exact pre-wave executable and cache key). Plans are cached
+    by workload digest."""
     if cfg.extensions:
         return None  # extension ops may read/write any carry channel
     from open_simulator_tpu.telemetry.ledger import engine_config_hash

@@ -1,10 +1,10 @@
-"""Synthetic replay workloads (shared by bench.py, tools/, tests).
+"""Synthetic replay workloads (shared by tools/ and tests).
 
 Builds a deterministic "day in the cluster": an initial cluster with a
 few running pods, arrival waves of Deployment batches, departures of
 earlier waves, one mid-trace fault, and node-template headroom for the
-autoscaler to scale into. Everything derives from fixed seeds so bench
-series and smoke digests are comparable run to run.
+autoscaler to scale into. Everything derives from fixed seeds so smoke
+digests and test results are comparable run to run.
 """
 
 from __future__ import annotations

@@ -17,8 +17,7 @@ The unified observability layer (ARCHITECTURE.md §8):
                engine's fail_counts + top-k score tensors
   ledger.py    flight recorder: one RunRecord JSON line per simulation
                into an on-disk size-capped ledger (--ledger-dir /
-               SIMON_LEDGER_DIR), diffed by `simon-tpu runs` and gated
-               by tools/bench_regress.py
+               SIMON_LEDGER_DIR), diffed by `simon-tpu runs`
 """
 
 from open_simulator_tpu.telemetry.registry import (  # noqa: F401

@@ -128,8 +128,8 @@ def explain_result(result, top_k: Optional[int] = None,
         wid = np.asarray(result.wave_id)
         report["waves"] = {
             # batched placement units only — the same semantic as
-            # bench.py's n_waves (fallback-scan pods are degenerate
-            # one-pod waves and are reported as scan_pods instead)
+            # WavePlan.stats()'s n_waves (fallback-scan pods are
+            # degenerate one-pod waves and are reported as scan_pods)
             "n_waves": int(np.unique(wid[wb]).size),
             "batched_pods": int(wb.sum()),
             "scan_pods": int((~wb).sum()),

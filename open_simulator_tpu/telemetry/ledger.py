@@ -2,17 +2,15 @@
 
 PR 3 built point-in-time observability (metrics, spans, explain); this
 module adds the time axis. Every simulation — CLI apply, chaos, a REST
-route, a capacity sweep, a bench shape — appends one structured
-``RunRecord`` JSON line to an on-disk ledger, so regressions,
-nondeterminism and config drift stay visible after the process exits
-(the BENCH_r01–r05 blind spot: five rounds of silently recorded
-TypeErrors that in-process metrics could never surface).
+route, a capacity sweep — appends one structured ``RunRecord`` JSON
+line to an on-disk ledger, so regressions, nondeterminism and config
+drift stay visible after the process exits.
 
 A record carries:
 
 * identity: ``run_id`` + wall-clock ``ts`` + ``surface`` (which entry
-  point ran: ``apply`` / ``chaos`` / ``server:<route>`` / ``bench`` /
-  ``sweep`` / ``simulate``),
+  point ran: ``apply`` / ``chaos`` / ``server:<route>`` / ``sweep`` /
+  ``simulate``),
 * a config fingerprint: EngineConfig content hash + the exec-cache
   bucket shape + a workload digest over the encoded SnapshotArrays —
   two runs with equal fingerprints asked the engine the same question,

@@ -311,7 +311,7 @@ class Histogram(Metric):
 
     def child_stats(self, **kv: str) -> Tuple[int, float]:
         """(count, sum) for one label set — the registry-as-source-of-truth
-        read path (bench.py reports the same numbers it exported)."""
+        read path for a histogram child."""
         key = tuple(str(kv[n]) for n in self.labelnames)
         with self._lock:
             child = self._children.get(key)

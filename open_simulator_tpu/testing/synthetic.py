@@ -1,4 +1,5 @@
-"""Synthetic benchmark clusters (shared by bench.py, tools/, the driver).
+"""Synthetic clusters (shared by `__graft_entry__.py`, `chip_smoke.py`,
+tools/ and tests).
 
 rich=False is the round-1..3-comparable workload (cpu/mem requests + one
 soft zone spread — most feature gates autodetect OFF). rich=True is the
@@ -6,7 +7,7 @@ honest all-ops-on workload: fractions of pods carry host ports, required
 pod-affinity, anti-affinity, hard and hostname spread, preferred pod/node
 affinities, node selectors and tolerations, and fractions of nodes carry
 taints / unschedulable marks — so make_config keeps every feature gate ON
-and a bench pays for the full op pipeline (VERDICT r3: gates must not
+and a run pays for the full op pipeline (VERDICT r3: gates must not
 hide regressions).
 """
 
@@ -39,7 +40,7 @@ def synthetic_objects(n_nodes: int, n_pods: int, rich: bool = False,
     (engine/waves.py) batches. bound > 0 pre-binds that fraction of pods
     via spec.nodeName, interleaved through the sequence — the
     cluster-dump replay shape. Both default off and leave the rich /
-    non-rich workloads byte-identical to the tracked bench series."""
+    non-rich workloads byte-identical to those without the knobs."""
     from open_simulator_tpu.k8s.objects import Node, Pod
 
     rng = np.random.RandomState(0)

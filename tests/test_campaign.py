@@ -635,14 +635,3 @@ def test_vanished_dump_quarantines_not_aborts(fleet_dir, tmp_path,
     assert quar["cluster"] == "vanished"
     assert quar["error"]["code"] == "E_SOURCE"
     assert quar["source"].startswith("unreadable-")
-
-
-def test_bench_campaign_contract(no_checkpoint):
-    """The fleet path's bench tag: clusters/sec > 0, quarantine count in
-    the line (the bench-regress series exists from day one)."""
-    import bench
-
-    dt, report, label = bench.run_campaign_bench(2, 4, 8)
-    assert dt > 0 and label.startswith("campaign2c")
-    assert report["totals"]["quarantined"] == 0
-    assert report["totals"]["completed"] == 2

@@ -98,6 +98,20 @@ def test_gen_doc(tmp_path):
     assert "simon-tpu.md" in files and "simon-tpu_apply.md" in files
 
 
+def test_gen_doc_writes_only_the_cli_tree(tmp_path):
+    """gen-doc documents the simon-tpu command tree and nothing else:
+    one page for the root and one per subcommand, and no page for a
+    script outside the package."""
+    from open_simulator_tpu.cli.main import build_parser
+
+    rc = main(["gen-doc", "--dir", str(tmp_path / "docs")])
+    assert rc == 0
+    files = set(os.listdir(tmp_path / "docs"))
+    [sub] = [a for a in build_parser()._subparsers._group_actions]
+    assert files == {"simon-tpu.md"} | {
+        f"simon-tpu_{name}.md" for name in sub.choices}
+
+
 def test_apply_more_pods_sweep_answer(capsys):
     """The more-pods scale corpus (reference example/application/more_pods
     analog): the batched sweep must land on a stable minimum node count."""

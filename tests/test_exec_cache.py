@@ -19,7 +19,6 @@ from open_simulator_tpu.encode.snapshot import (
 )
 from open_simulator_tpu.engine import exec_cache
 from open_simulator_tpu.engine.exec_cache import (
-    BucketPolicy,
     ExecutableCache,
     bucket_dim,
     bucket_shape,
@@ -61,7 +60,7 @@ def _snapshot(n_pods=12, pod_cpu="1500m", max_new=12):
         EncodeOptions(max_new_nodes=max_new, new_node_template=template))
 
 
-# ---- bucketing policy ---------------------------------------------------
+# ---- bucket boundaries --------------------------------------------------
 
 def test_bucket_dim_pow2_then_linear_tail():
     assert [bucket_dim(n, 16, 16) for n in (1, 2, 3, 5, 9, 16)] == \
@@ -73,15 +72,41 @@ def test_bucket_dim_pow2_then_linear_tail():
     assert bucket_dim(0, 16, 16) == 0
 
 
-def test_bucket_shape_keeps_northstar_exact():
-    # the tracked bench shape must sit ON a boundary (no pad, comparable
-    # series) under the default policy
-    assert bucket_shape(5120, 51200) == (5120, 51200)
+@pytest.mark.parametrize("shape,bucket", [
+    # 5,120 x 51,200 sits ON a boundary (no pad)
+    ((5120, 51200), (5120, 51200)),
+    # the benchmark cells: 5,120 nodes + 64 template slots
+    ((5184, 51200), (6144, 51200)),
+    # the doubling region of both axes
+    ((13, 37), (16, 64)),
+    # one past each doubling region: the first linear step
+    ((1025, 2049), (2048, 4096)),
+])
+def test_bucket_shape_keeps_northstar_exact(shape, bucket):
+    assert bucket_shape(*shape) == bucket
 
 
-def test_bucket_policy_disable():
-    p = BucketPolicy(enabled=False)
-    assert bucket_shape(13, 37, p) == (13, 37)
+def test_bucket_shape_ignores_the_retired_env_switch():
+    """Bucketing is decided from the shape alone: the environment
+    variable that once turned it off changes nothing, even when it is
+    set before the module is imported."""
+    import subprocess
+    import sys
+
+    env = dict(os.environ, SIMON_BUCKETING="0", JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "from open_simulator_tpu.engine.exec_cache import bucket_shape; "
+         "print(bucket_shape(13, 37))"],
+        env=env, capture_output=True, text=True, check=True,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    assert out.stdout.strip() == "(16, 64)"
+
+
+def _unbucketed(monkeypatch):
+    """Run every entry point on its unpadded shape (the reference that
+    bucketing must equal)."""
+    monkeypatch.setattr(exec_cache, "bucket_shape", lambda n, p: (n, p))
 
 
 def test_axis_declarations_cover_every_field():
@@ -136,7 +161,7 @@ def test_bucketed_simulate_matches_unbucketed(monkeypatch):
     and gpu picks must be bit-identical with the padding off."""
     cluster, apps = _cluster(5, 11)
     res_pad = simulate(cluster, apps)
-    monkeypatch.setattr(exec_cache, "DEFAULT_POLICY", BucketPolicy(enabled=False))
+    _unbucketed(monkeypatch)
     cluster2, apps2 = _cluster(5, 11)
     res_raw = simulate(cluster2, apps2)
     assert res_pad.placements() == res_raw.placements()
@@ -144,6 +169,27 @@ def test_bucketed_simulate_matches_unbucketed(monkeypatch):
         [u.reason for u in res_raw.unscheduled_pods]
     np.testing.assert_array_equal(res_pad.fail_counts, res_raw.fail_counts)
     assert res_pad.n_active_nodes == res_raw.n_active_nodes == 5
+
+
+@pytest.mark.parametrize("pools", [0, 4])
+def test_bucketed_sweep_matches_unbucketed(monkeypatch, pools):
+    """The capacity sweep on its padded bucket (15 -> 16 nodes, 37 -> 64
+    pods) gives the plan of the unpadded run: same counts, verdicts and
+    every lane's node assignments. pools=4 runs the wave path, whose
+    plan sees the padded pod tail."""
+    from open_simulator_tpu.telemetry.ledger import plan_digest
+    from open_simulator_tpu.testing.synthetic import synthetic_snapshot
+
+    snap = synthetic_snapshot(10, 37, 5, pools=pools)
+    cfg = make_config(snap)
+    counts = list(range(6))
+    padded = capacity_sweep(snap, cfg, counts=counts)
+    _unbucketed(monkeypatch)
+    raw = capacity_sweep(snap, cfg, counts=counts)
+    assert padded.counts == raw.counts == counts
+    np.testing.assert_array_equal(padded.nodes_per_scenario,
+                                  raw.nodes_per_scenario)
+    assert plan_digest(padded)["digest"] == plan_digest(raw)["digest"]
 
 
 def test_same_bucket_simulate_zero_recompiles():

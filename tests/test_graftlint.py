@@ -217,6 +217,14 @@ def test_repo_tree_is_lint_clean():
     assert fs == [], "graftlint findings at HEAD:\n" + format_text(fs)
 
 
+def test_default_lint_paths_exist():
+    # a stale entry would silently shrink what `simon-tpu lint` checks
+    from open_simulator_tpu.analysis.report import DEFAULT_PATHS
+
+    for path in DEFAULT_PATHS:
+        assert os.path.exists(os.path.join(repo_root(), path)), path
+
+
 def test_assert_clean_raises_structured_lint_error():
     with pytest.raises(LintError) as exc:
         assert_clean(root=FIXTURES, paths=["gl5_dtype.py"])

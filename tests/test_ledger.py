@@ -1,9 +1,8 @@
-"""Flight recorder: ledger round-trip, capture wiring, diffing, rotation,
-the runs CLI, and the bench regression gate (ARCHITECTURE.md §10)."""
+"""Flight recorder: ledger round-trip, capture wiring, diffing, rotation
+and the runs CLI (ARCHITECTURE.md §10)."""
 
 import json
 import os
-import sys
 
 import pytest
 
@@ -13,9 +12,6 @@ from open_simulator_tpu.telemetry import ledger
 from open_simulator_tpu.testing.builders import make_fake_node, make_fake_pod
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, os.path.join(REPO, "tools"))
-
-import bench_regress  # noqa: E402
 
 
 @pytest.fixture
@@ -37,8 +33,7 @@ def _small_cluster():
 
 
 def _record(run_id="r0", ts=1000.0, surface="apply", digest="d0",
-            engine="e0", workload="w0", value=None, shape=None,
-            phases=None):
+            engine="e0", workload="w0", phases=None):
     rec = {
         "schema": 1, "run_id": run_id, "ts": ts, "surface": surface,
         "wall_s": 1.0,
@@ -50,10 +45,6 @@ def _record(run_id="r0", ts=1000.0, surface="apply", digest="d0",
         "result": {"placed": 4, "unplaced": 0, "digest": digest},
         "tags": {},
     }
-    if value is not None:
-        rec["surface"] = "bench"
-        rec["tags"] = {"shape": shape or "8n_x16p_x4s", "value": value,
-                       "preset": "demo"}
     return rec
 
 
@@ -187,19 +178,6 @@ def test_chaos_records_one_run(led):
     assert [r["surface"] for r in recs] == ["chaos"]
     assert recs[0]["result"]["digest"] == ledger.report_digest(report)["digest"]
     assert recs[0]["tags"]["events"] == 1
-
-
-def test_bench_records_shape_and_value(led):
-    sys.path.insert(0, REPO)
-    import bench
-
-    snap = bench.build(4, 8, 2)
-    bench.run_batched(snap, 2, preset="demo")
-    [rec] = led.records(surface="bench")
-    assert rec["tags"]["preset"] == "demo"
-    assert rec["tags"]["shape"] == bench.shape_label(4, 8, 2)
-    assert rec["tags"]["value"] > 0 and rec["tags"]["seconds"] > 0
-    assert rec["result"]["digest"] and rec["fingerprint"]["engine"]
 
 
 def test_compile_cache_metric_delta_flips_to_hit(led):
@@ -342,53 +320,3 @@ def test_apply_cli_two_runs_identical_digests(led, capsys):
     assert main(["runs", "--ledger-dir", led.root, "diff", "prev", "last"]) == 0
     out = capsys.readouterr().out
     assert "IDENTICAL" in out and "sweep" in out
-
-
-# ---- bench regression gate ----------------------------------------------
-
-
-def test_bench_regress_no_op_paths(led, capsys):
-    # empty ledger -> clean no-op
-    assert bench_regress.main(["--ledger-dir", led.root]) == 0
-    assert "nothing to gate" in capsys.readouterr().out
-    # one record per shape -> still a no-op (no history)
-    led.append(_record("r1", ts=1.0, value=100.0))
-    assert bench_regress.main(["--ledger-dir", led.root]) == 0
-    assert "no history" in capsys.readouterr().out
-
-
-def test_bench_regress_passes_within_threshold(led, capsys):
-    for i, v in enumerate([100.0, 104.0, 96.0, 98.0]):
-        led.append(_record(f"r{i}", ts=float(i), value=v))
-    assert bench_regress.main(["--ledger-dir", led.root]) == 0
-    assert "OK" in capsys.readouterr().out
-
-
-def test_bench_regress_fails_on_slowed_record(led, capsys):
-    for i, v in enumerate([100.0, 102.0, 98.0]):
-        led.append(_record(f"r{i}", ts=float(i), value=v))
-    # synthetically slowed newest record: 40% below the trailing median
-    led.append(_record("slow", ts=99.0, value=60.0))
-    assert bench_regress.main(["--ledger-dir", led.root]) == 1
-    out = capsys.readouterr().out
-    assert "REGRESSION" in out and "FAILED" in out
-    # a tolerant threshold lets the same ledger pass
-    assert bench_regress.main(
-        ["--ledger-dir", led.root, "--threshold", "0.5"]) == 0
-
-
-def test_bench_regress_gates_shapes_independently(led, capsys):
-    for i, v in enumerate([100.0, 100.0]):
-        led.append(_record(f"a{i}", ts=float(i), value=v, shape="s_a"))
-    led.append(_record("b0", ts=10.0, value=50.0, shape="s_b"))
-    led.append(_record("b1", ts=11.0, value=10.0, shape="s_b"))  # -80%
-    assert bench_regress.main(["--ledger-dir", led.root]) == 1
-    out = capsys.readouterr().out
-    assert "s_b" in out and "FAILED" in out and "s_a" in out
-
-
-def test_bench_regress_without_any_ledger(monkeypatch, capsys):
-    monkeypatch.delenv(ledger.LEDGER_DIR_ENV, raising=False)
-    ledger.configure(None)
-    assert bench_regress.main([]) == 0
-    assert "no ledger configured" in capsys.readouterr().out

@@ -10,11 +10,14 @@ Two layers:
   interleaved forced binds, the all-ops rich workload, GPU share, host
   ports) asserting the wave engine's assignments, fail_counts, every
   carry leaf of the final state, and the ledger result digest are
-  BIT-IDENTICAL to the pure scan (`SIMON_WAVES=0` / waves=None) — the
-  exactness contract waves are allowed to exist under.
+  BIT-IDENTICAL to the pure scan (waves=None, or the `scan_only`
+  fixture for the entry points) — the exactness contract waves are
+  allowed to exist under.
 """
 
 from __future__ import annotations
+
+import contextlib
 
 import numpy as np
 import pytest
@@ -31,6 +34,23 @@ from open_simulator_tpu.testing.synthetic import synthetic_snapshot
 
 
 # ---- helpers -------------------------------------------------------------
+
+
+@pytest.fixture
+def scan_only(monkeypatch):
+    """A context in which every entry point runs the sequential scan.
+
+    Each caller imports `waves_for` at call time, so one patch of the
+    module attribute turns wave planning off everywhere: the scan is
+    the reference the wave engine must equal."""
+
+    @contextlib.contextmanager
+    def ctx():
+        with monkeypatch.context() as m:
+            m.setattr(W, "waves_for", lambda *a, **kw: None)
+            yield
+
+    return ctx
 
 
 def _pool_nodes(n, pools, **kw):
@@ -240,12 +260,27 @@ def test_class_cap_returns_pure_scan():
     assert plan.n_pods == 12  # uncapped analysis still runs below the cap
 
 
-def test_simon_waves_env_disables(monkeypatch):
+def test_waves_ignore_the_retired_env_switch(monkeypatch):
+    # wave planning is decided from the input alone: the environment
+    # variable that once turned it off changes nothing
     snap = synthetic_snapshot(16, 64, 0, pools=8)
     monkeypatch.setenv("SIMON_WAVES", "0")
     cfg = make_config(snap)._replace(fail_reasons=False)
-    assert not cfg.wave_scheduling
-    assert W.waves_for(snap.arrays, cfg) is None
+    plan = W.waves_for(snap.arrays, cfg)
+    assert plan is not None and plan.wave_fraction == 1.0
+
+
+def test_all_gates_on_for_rich_build():
+    """The honesty premise: the rich synthetic workload keeps every
+    make_config feature gate ON (VERDICT r3 #2), so a run on it pays
+    for the full op pipeline."""
+    cfg = make_config(synthetic_snapshot(64, 128, 8, rich=True))
+    for gate in ("enable_ports", "enable_pod_affinity", "enable_anti_affinity",
+                 "enable_spread_hard", "enable_spread_soft", "enable_pref",
+                 "enable_node_aff_score", "enable_taint_score",
+                 "spread_hostname", "enable_unsched", "enable_class_aff",
+                 "enable_class_taint"):
+        assert getattr(cfg, gate), gate
 
 
 # ---- equivalence properties ---------------------------------------------
@@ -375,41 +410,41 @@ def test_equiv_host_ports_across_pools():
     assert plan is not None
 
 
-@pytest.mark.parametrize("lanes", [4, 64])
-def test_equiv_sweep_digest(monkeypatch, lanes):
+@pytest.mark.parametrize("lanes", [4, 64, 8])
+def test_equiv_sweep_digest(monkeypatch, scan_only, lanes):
     # the product sweep path with waves on vs off must produce
     # bit-identical placements and plan digests (the acceptance
     # criterion's ledger-digest form): a 4-lane capacity_bisect over
-    # waves of 8, and a 64-lane capacity_sweep over GRID waves of 32,
-    # the wave step at the lane count and width of a pools sweep
+    # waves of 8, a 64-lane capacity_sweep over GRID waves of 32 (the
+    # wave step at the lane count and width of a pools sweep), and an
+    # 8-lane capacity_bisect over GRID waves of 32 (the lane count and
+    # width of a pools capacity question)
     from open_simulator_tpu.parallel.sweep import capacity_bisect, capacity_sweep
     from open_simulator_tpu.telemetry.ledger import plan_digest
 
     monkeypatch.delenv("SIMON_LEDGER_DIR", raising=False)
     monkeypatch.delenv("SIMON_CHECKPOINT_DIR", raising=False)
     if lanes == 4:
-        snap = synthetic_snapshot(16, 96, 8, pools=8)
-
-        def run(cfg):
-            return capacity_bisect(snap, cfg, max_new=8, lanes=4)
+        max_new = 8
+        snap = synthetic_snapshot(16, 96, max_new, pools=8)
     else:
-        snap = synthetic_snapshot(64, 128, 64, pools=32)
+        max_new = 64
+        snap = synthetic_snapshot(64, 128, max_new, pools=32)
         grid = W.waves_for(snap.arrays, make_config(snap)._replace(
             fail_reasons=False))
         assert grid.segments == ((0, 128, W.GRID, 32),)
 
-        def run(cfg):
-            return capacity_sweep(snap, cfg, counts=list(range(lanes)))
-    plans = {}
-    for env in ("1", "0"):
-        monkeypatch.setenv("SIMON_WAVES", env)
+    def run():
         cfg = make_config(snap)
-        assert cfg.wave_scheduling == (env == "1")
-        plans[env] = run(cfg)
-    assert np.array_equal(plans["1"].nodes_per_scenario,
-                          plans["0"].nodes_per_scenario)
-    assert (plan_digest(plans["1"])["digest"]
-            == plan_digest(plans["0"])["digest"])
+        if lanes == 64:
+            return capacity_sweep(snap, cfg, counts=list(range(lanes)))
+        return capacity_bisect(snap, cfg, max_new=max_new, lanes=lanes)
+
+    waves = run()
+    with scan_only():
+        scan = run()
+    assert np.array_equal(waves.nodes_per_scenario, scan.nodes_per_scenario)
+    assert plan_digest(waves)["digest"] == plan_digest(scan)["digest"]
 
 
 def test_simulate_reports_waves():
@@ -431,15 +466,13 @@ def test_simulate_reports_waves():
     assert "wave" in entry and entry["wave_path"] in ("batched", "scan")
 
 
-def test_equiv_simulate_result_digest(monkeypatch):
+def test_equiv_simulate_result_digest(scan_only):
     # end-to-end simulate(): identical result digest with waves on/off
     from open_simulator_tpu.core import AppResource, simulate
     from open_simulator_tpu.k8s.loader import ClusterResources
     from open_simulator_tpu.telemetry.ledger import result_digest
 
-    digests = {}
-    for env in ("1", "0"):
-        monkeypatch.setenv("SIMON_WAVES", env)
+    def run():
         cluster = ClusterResources()
         cluster.nodes = _pool_nodes(8, 8)
         app = ClusterResources()
@@ -447,8 +480,13 @@ def test_equiv_simulate_result_digest(monkeypatch):
             make_fake_pod(f"p{i}", node_selector={"pool": f"p{i % 8}"})
             for i in range(24)]
         res = simulate(cluster, [AppResource(name="a", resources=app)])
-        digests[env] = result_digest(res)["digest"]
-    assert digests["1"] == digests["0"]
+        return res, result_digest(res)["digest"]
+
+    res_waves, d_waves = run()
+    with scan_only():
+        res_scan, d_scan = run()
+    assert res_waves.wave_id is not None and res_scan.wave_id is None
+    assert d_waves == d_scan
 
 
 # ---- satellite: disabled-ledger sweeps never fingerprint -----------------

@@ -7,9 +7,7 @@ chip path.
 Runs `batched_schedule` over an 8-virtual-CPU-device "scenario" mesh
 and asserts the node assignments — and their ledger result
 digest — are IDENTICAL to the single-device run of the same workload.
-The MULTICHIP_r01–r05 records all silently carried the same pre-PR-1
-scan-arity crash because nothing gated the sharded path between rounds;
-this tool is that gate, fast enough for tools/smoke.sh.
+It gates the sharded path, fast enough for tools/smoke.sh.
 
 Three workloads, chosen to exercise the paths that can rot
 independently:
@@ -21,20 +19,13 @@ independently:
   (engine/waves.py) batches the whole sequence — so the gate covers
   GSPMD-sharded wave execution, not just the sequential scan.
 
-Two more gates ride the same process (ISSUE 19):
-
-* **recompile gate** — two same-bucket mesh launches plus a
-  donated-carry round-2 must show EXACTLY ONE
-  `simon_compile_cache_total{fn=mesh_schedule}` miss, so the old
-  fresh-`jit(vmap(lambda ...))`-per-call shape (a full recompile per
-  bisect round) can never silently return; the donated round's digest
-  must equal the fresh rounds' (the §9 x*0 reset contract, under the
-  mesh);
-* **perf record** — a timed donated-carry loop on the 8-device mesh
-  lands one tagged "bench" RunRecord (preset=multichip, scenarios/sec,
-  mesh split, digest) in SIMON_LEDGER_DIR (or a temp ledger when
-  unset): the enforced, regressable replacement for the rotted
-  MULTICHIP_r01–r05 snapshots.
+A recompile gate rides the same process: two same-bucket mesh launches
+plus a donated-carry round-2 must show EXACTLY ONE
+`simon_compile_cache_total{fn=mesh_schedule}` miss, so the old
+fresh-`jit(vmap(lambda ...))`-per-call shape (a full recompile per
+bisect round) can never silently return; the donated round's digest
+must equal the fresh rounds' (the §9 x*0 reset contract, under the
+mesh).
 
 Exit 0 = all digests equal and the gates hold; any mismatch, miss-count
 drift, or crash exits nonzero.
@@ -44,8 +35,6 @@ from __future__ import annotations
 
 import os
 import sys
-import tempfile
-import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -77,7 +66,6 @@ def main() -> int:
         batched_schedule,
         make_mesh,
     )
-    from open_simulator_tpu.telemetry import ledger
     from open_simulator_tpu.telemetry.ledger import array_result_digest
 
     mesh = make_mesh(n_scenario=N_DEVICES, devices=devices)
@@ -151,38 +139,6 @@ def main() -> int:
               f"fresh rounds {d1}/{d2} (the x*0 reset contract broke "
               f"under the mesh)", file=sys.stderr)
         failures += 1
-
-    # ---- tagged perf record: a timed donated-carry loop on the mesh
-    # (pure cache hits — compiled above), recorded like a bench preset so
-    # `simon-tpu runs` / bench_regress can read the multichip series
-    if not ledger.enabled():
-        ledger.configure(tempfile.mkdtemp(prefix="multichip-ledger-"))
-    rounds = 3
-    carry = None
-    t0 = time.perf_counter()
-    for _ in range(rounds):
-        out = batched_schedule(arrs, masks, cfg, mesh=mesh, waves=plan,
-                               carry=carry)
-        carry = out.state
-    dt = time.perf_counter() - t0
-    lanes = int(masks.shape[0])
-    per_sec = lanes * rounds / dt
-    n_chips = int(mesh.devices.size)
-    split = "x".join(str(s) for s in mesh.shape.values())
-    with ledger.run_capture("bench") as cap:
-        cap.set_config(cfg, snapshot=snap, arrs=arrs)
-        cap.set_result_info(**array_result_digest(np.asarray(out.node)))
-        cap.tag("preset", "multichip")
-        cap.tag("shape", f"{snap.n_nodes}n-{snap.n_pods}p-{lanes}s-{split}")
-        cap.tag("devices", n_chips)
-        cap.tag("mesh", split)
-        cap.tag("lanes", lanes)
-        cap.tag("seconds", round(dt, 6))
-        cap.tag("value", round(per_sec, 3))
-        cap.tag("scenarios_per_sec_per_chip", round(per_sec / n_chips, 3))
-    print(f"multichip perf: {per_sec:.1f} scenarios/sec on {n_chips} "
-          f"virtual devices (mesh {split}, {rounds} donated rounds) -> "
-          f"ledger dir {ledger.ledger_dir()}")
 
     if failures:
         print(f"multichip-smoke FAILED: {failures} gate(s) failed",

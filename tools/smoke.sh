@@ -60,16 +60,7 @@ if [ "$rc" -ne 0 ]; then
 fi
 
 echo
-echo "== bench contract (demo preset emits a valid JSON line) =="
-make bench-smoke
-rc=$?
-if [ "$rc" -ne 0 ]; then
-  echo "smoke FAILED: bench-smoke exited $rc" >&2
-  exit "$rc"
-fi
-
-echo
-echo "== run ledger (flight recorder): apply x2, diff, regress gate =="
+echo "== run ledger (flight recorder): apply x2, diff =="
 SMOKE_LEDGER="$(mktemp -d)"
 env JAX_PLATFORMS=cpu SIMON_LEDGER_DIR="$SMOKE_LEDGER" python - <<'PYEOF'
 # the demo apply twice in one process: records 2 "apply" RunRecords with
@@ -108,13 +99,6 @@ env JAX_PLATFORMS=cpu python -m open_simulator_tpu.cli runs \
 rc=$?
 if [ "$rc" -ne 0 ]; then
   echo "smoke FAILED: runs diff exited $rc" >&2
-  exit "$rc"
-fi
-# no bench records in the smoke ledger -> the gate must no-op cleanly
-env SIMON_LEDGER_DIR="$SMOKE_LEDGER" make bench-regress
-rc=$?
-if [ "$rc" -ne 0 ]; then
-  echo "smoke FAILED: bench-regress exited $rc (expected clean no-op)" >&2
   exit "$rc"
 fi
 rm -rf "$SMOKE_LEDGER"
