@@ -565,6 +565,140 @@ def _const_outputs(arrs: SnapshotArrays, cfg: EngineConfig,
             jnp.zeros((c, c_parts, k_top), jnp.float32))
 
 
+# ---- footprint-narrowed GRID waves ---------------------------------------
+# A GRID segment with a narrowed width K (WavePlan.narrow, engine/waves.py)
+# runs each pod's unchanged _step over the K slots of its class's
+# footprint (class_affinity & class_taint & ~unschedulable, over the
+# filters the config runs) instead of the whole node axis: its node ids
+# in ascending order, padded with slots that are never active. Static
+# node-axis inputs and the lane's active row are gathered once per
+# launch into [S, K, ...] tables for the segment's S scheduled classes,
+# which a pod reads by its row among them. Headroom rides the segment's
+# scan as an [S, K, R] copy beside the carry, read by that row and
+# updated by each wave's binds, so no wave gathers headroom rows (a
+# gather of 8,192 node rows a wave cost ~50 us on v5e at any lane count,
+# as much as a whole dense wave at 8 lanes); only host ports, where a
+# config has them, are gathered per wave. The planner admits a segment
+# only where its scheduled classes' footprints are pairwise disjoint,
+# so each node has at most one slot to update. The pick maps back
+# through the ascending ids, so the merge works in node ids.
+
+# SnapshotArrays fields a narrowing-exact step reads along the node
+# axis: [N, ...] rows, [A, N, ...] columns, and [C, N] per-class rows,
+# which narrow on the diagonal (class c over its own footprint)
+_NARROW_ROWS = ("alloc", "unschedulable")
+_NARROW_COLS = ("has_key", "topo_onehot")
+_NARROW_CLASS = ("class_affinity", "class_taint", "class_node_aff_score",
+                 "class_taint_prefer")
+
+
+class _NarrowTables(NamedTuple):
+    row: jnp.ndarray        # [C] i32 a class's row among the segment's
+    idx: jnp.ndarray        # [S, K] i32 footprint node ids, ascending
+    valid: jnp.ndarray      # [S, K] bool, False on pad slots
+    slot_of: jnp.ndarray    # [N] i32 a node's flat slot (row * K + slot), -1
+    rows: Dict[str, jnp.ndarray]     # field -> [S, K, ...] / [S, A, K, ...]
+    classes: Dict[str, jnp.ndarray]  # field -> [S, K], indexed by _step
+    active: jnp.ndarray     # [S, K] bool, False on pad slots
+    inv_alloc: jnp.ndarray  # [S, K, R]
+    hoisted: Dict[str, jnp.ndarray]  # ActiveHoist field -> its [S, ...] rows
+
+
+def _narrow_tables(arrs, cfg, active, hoisted, inv_alloc, k: int,
+                   classes) -> _NarrowTables:
+    """The narrowed tables of one launch (one lane's active row) for
+    the segment classes ``classes``. The planner sized K above each of
+    their footprints, so no row a narrowed pod reads is cut short."""
+    from open_simulator_tpu.engine.waves import footprints
+
+    cls = np.asarray(classes, np.int32)
+    n_nodes = arrs.alloc.shape[0]
+    fp = footprints(arrs, cfg)[cls]
+    idx = jax.vmap(lambda r: jnp.nonzero(r, size=k, fill_value=0)[0])(fp)
+    idx = idx.astype(jnp.int32)
+    valid = (jax.lax.iota(jnp.int32, k)[None, :]
+             < jnp.sum(fp, axis=1, dtype=jnp.int32)[:, None])
+    row = np.zeros(arrs.class_affinity.shape[0], np.int32)
+    row[cls] = np.arange(cls.size, dtype=np.int32)
+    # the classes' footprints are pairwise disjoint: one slot a node
+    slot_of = jnp.full((n_nodes,), -1, jnp.int32).at[
+        jnp.where(valid, idx, n_nodes).reshape(-1)].set(
+        jax.lax.iota(jnp.int32, cls.size * k), mode="drop")
+
+    def diag(t):  # [C, N] -> [S, K]
+        return jnp.take_along_axis(t[cls], idx, axis=1)
+
+    rows = {f: getattr(arrs, f)[idx] for f in _NARROW_ROWS}
+    rows.update({f: jnp.moveaxis(getattr(arrs, f)[:, idx], 0, 1)
+                 for f in _NARROW_COLS})
+    return _NarrowTables(
+        row=jnp.asarray(row), idx=idx, valid=valid, slot_of=slot_of,
+        rows=rows, classes={f: diag(getattr(arrs, f)) for f in _NARROW_CLASS},
+        active=active[idx] & valid, inv_alloc=inv_alloc[idx],
+        hoisted=dict(dom_idx=jnp.moveaxis(hoisted.dom_idx[:, idx], 0, 1),
+                     elig_host=diag(hoisted.elig_host),
+                     domain_has=hoisted.domain_has[cls],
+                     any_elig=hoisted.any_elig[cls]))
+
+
+def _narrowed_step(arrs, cfg, hoisted, gcr_seg, wvec, tabs: _NarrowTables,
+                   state, headroom, x):
+    """_step for one pod over its class's footprint slots, against the
+    segment's per-class headroom rows: (outputs in node ids, the picked
+    slot). Exact for narrowing-exact configs (waves.narrowing_exact):
+    every node-axis reduction there runs over the feasible set, which
+    lies in the footprint, and "min index among maxima" over ascending
+    ids is the min node id."""
+    import dataclasses
+
+    r = tabs.row[x["class_id"]]
+    ids = tabs.idx[r]                                          # [K]
+    slots = jax.lax.iota(jnp.int32, ids.shape[0])
+    rows = {f: t[r] for f, t in tabs.rows.items()}
+    # one static-score spec per slot (its node's allocatable, the same
+    # floats as its spec_alloc row): the per-spec score table then needs
+    # no gather of a per-pod spec id row
+    rows.update(spec_alloc=rows["alloc"], spec_id=slots)
+    view = dataclasses.replace(arrs, **rows, **tabs.classes)
+    hv = hoisted._replace(**{**tabs.hoisted,
+                             "dom_idx": tabs.hoisted["dom_idx"][r]})
+    sv = state._replace(headroom=headroom[r],
+                        ports_used=state.ports_used[ids])
+    # the step reads every per-class table by the pod's row among them
+    _, ys = _step(view, tabs.active[r], cfg, hv, tabs.inv_alloc[r],
+                  gcr_seg, wvec, sv, {**x, "class_id": r})
+    slot = ys[0]
+    picked = (x["forced_node"] == -1) & (slot >= 0)
+    # the slot's node id by a select-reduce over the row (a gather of
+    # one id per pod and lane costs more on the TPU than this pass)
+    node = jnp.where(picked, jnp.max(jnp.where(slots == slot, ids, -1)), slot)
+    return (node,) + tuple(ys[1:]), jnp.where(picked, slot, -1)
+
+
+def _narrowed_grid_step(arrs, cfg, hoisted, gcr_seg, wvec, tabs, carry, xw):
+    """_grid_step over footprints: the wave's pods against the
+    wave-start carry and per-class headroom rows, one merged bind, and
+    the binds' requests taken from their slots' headroom."""
+    state, headroom = carry
+    ys, picked = jax.vmap(lambda xx: _narrowed_step(
+        arrs, cfg, hoisted, gcr_seg, wvec, tabs, state, headroom, xx))(xw)
+    new_state = _wave_merge(arrs, cfg, state, xw, ys[0], None)
+    s_n, k = tabs.idx.shape
+    forced = xw["forced_node"]
+    fslot = tabs.slot_of[jnp.maximum(forced, 0)]
+    on_slot = (forced >= 0) & (fslot >= 0)
+    # the row and slot each bind lands in (row s_n: no slot, dropped)
+    row = jnp.where(forced == -1, tabs.row[xw["class_id"]],
+                    jnp.where(on_slot, fslot // k, s_n))
+    col = jnp.where(forced == -1, picked, jnp.where(on_slot, fslot % k, -1))
+    hit = jax.lax.iota(jnp.int32, k)[None, :] == col[:, None]      # [W, K]
+    # integer-valued requests: the adds are exact in any order, and a
+    # -0.0 leaves every other slot as it is
+    taken = jnp.where(hit[:, :, None], -xw["req"][:, None, :], -0.0)
+    headroom = headroom.at[row].add(taken, mode="drop")
+    return (new_state, headroom), ys
+
+
 def _grid_step(arrs, active, cfg, hoisted, inv_alloc, gcr_seg, wvec, state,
                xw):
     """One macro-step of a GRID segment: batched filter+score for the
@@ -587,7 +721,8 @@ def _run_wave_plan(arrs, active, cfg, hoisted, inv_alloc, gcr_seg, wvec,
     step = functools.partial(_step, arrs, active, cfg, hoisted, inv_alloc,
                              gcr_seg, wvec)
     outs = []
-    for lo, hi, kind, w in waves.segments:
+    narrow_tabs: Dict = {}  # by (K, classes), built once a launch
+    for (lo, hi, kind, w), narrow in zip(waves.segments, waves.narrow):
         a0, a1 = lo - k, hi - k
         xseg = {name: v[a0:a1] for name, v in xs.items()}
         c = a1 - a0
@@ -604,11 +739,21 @@ def _run_wave_plan(arrs, active, cfg, hoisted, inv_alloc, gcr_seg, wvec,
                 state = _wave_merge(arrs, cfg, state, sub,
                                     sub["forced_node"], None)
         elif kind == wave_mod.GRID:
-            gstep = functools.partial(_grid_step, arrs, active, cfg,
-                                      hoisted, inv_alloc, gcr_seg, wvec)
             xg = {name: v.reshape((c // w, w) + v.shape[1:])
                   for name, v in xseg.items()}
-            state, ysg = _scan_xs(gstep, state, xg, 1)
+            if narrow[0]:
+                if narrow not in narrow_tabs:
+                    narrow_tabs[narrow] = _narrow_tables(
+                        arrs, cfg, active, hoisted, inv_alloc, *narrow)
+                tabs = narrow_tabs[narrow]
+                gstep = functools.partial(_narrowed_grid_step, arrs, cfg,
+                                          hoisted, gcr_seg, wvec, tabs)
+                (state, _), ysg = _scan_xs(
+                    gstep, (state, state.headroom[tabs.idx]), xg, 1)
+            else:
+                gstep = functools.partial(_grid_step, arrs, active, cfg,
+                                          hoisted, inv_alloc, gcr_seg, wvec)
+                state, ysg = _scan_xs(gstep, state, xg, 1)
             outs.append(jax.tree_util.tree_map(
                 lambda t: t.reshape((c,) + t.shape[2:]), ysg))
         else:  # BATCH: one wave, chunked to bound the [chunk, N] tensors
@@ -1584,6 +1729,8 @@ def schedule_pods(
     inv_alloc = jnp.where(arrs.alloc > 0,
                           div(1.0, jnp.where(arrs.alloc > 0, arrs.alloc, 1.0)), 0.0)
     if live is not None:
+        if waves is not None and any(k for k, _ in waves.narrow):
+            live.add("class_id")  # a narrowed pod reads its class's row
         xs = {k: v for k, v in xs.items() if k in live}
     gcr_seg = _gcr_segments(cfg, scan_arrs)
     if gcr_seg is not None:

@@ -234,3 +234,71 @@ def test_wave_step_scores_each_node_once(wave_body, lanes):
                        body)
     assert picks, "no [lanes, wave] index fusion: the guard checks nothing"
     assert any(set(re.findall(r"%([\w.\-]+)", ops)) & rows for ops in picks)
+
+
+@pytest.fixture(scope="module")
+def narrowed_wave_body(topo, no_persistent_cache):
+    """(loop body text, node bucket, plan) of the pools lane program at
+    64 lanes and the benchmark's node count: 5,120 nodes in 32 tenant
+    pools plus 64 template slots in pool p0 (a 6,144-node bucket),
+    3,200 pods in waves of 32."""
+    from jax.sharding import SingleDeviceSharding
+
+    fn, arrs, carry, mask_shape, waves = _lane_program(
+        5120, 3200, 64, 64, rich=False, pools=32)
+    hlo = _compile_one_chip(SingleDeviceSharding(topo.devices[0]),
+                            fn, arrs, carry, mask_shape).as_text()
+    bodies = re.findall(r"body=%([\w.\-]+)", hlo)
+    assert len(bodies) == 1, "expected the one GRID wave loop"
+    return _computations(hlo)[bodies[0]], arrs.alloc.shape[0], waves
+
+
+def test_pools_wave_step_runs_over_footprints(narrowed_wave_body):
+    """Each pool's pods can only land on its 160 nodes (224 in p0, with
+    the templates), so the plan narrows the GRID segment to 256 slots
+    and no op of the wave loop's body may compute a [lanes, wave width,
+    N] f32 row: those passes over all 6,144 node slots were 93% of a
+    pools5k.sweep64 launch on v5e."""
+    body, n, waves = narrowed_wave_body
+    assert waves.narrow[0][0] == 256 and waves.narrowed_fraction > 0.75
+    assert re.search(r"= \(?f32\[64,32,256\]", body), (
+        "no [lanes, wave, K] f32 row in the wave body: the guard checks "
+        "nothing")
+    wide = [line.strip()[:160] for line in body.splitlines()
+            if re.search(rf"f32\[64,32,{n}\]", line)]
+    assert not wide, wide
+
+
+def _ops_without_metadata(hlo):
+    """Every computation's ops, without source locations."""
+    return {name: re.sub(r", metadata=\{[^}]*\}", "", body)
+            for name, body in _computations(hlo).items()}
+
+
+@pytest.mark.parametrize("pools", [0, 32])
+def test_lane_program_without_narrowing_is_unchanged(
+        topo, no_persistent_cache, monkeypatch, pools):
+    """A program the plan does not narrow compiles op for op as it did
+    before narrowing existed: the sequential scan of a cluster with no
+    wave plan (the spread5k shape), and a GRID plan whose width K (128
+    slots for pools of 2 of 64 nodes) exceeds half of the node axis."""
+    from jax.sharding import SingleDeviceSharding
+
+    from open_simulator_tpu.engine import exec_cache
+    from open_simulator_tpu.engine import waves as W
+
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def build():
+        fn, arrs, carry, mask_shape, waves = _lane_program(
+            64, 128, 8, 8, rich=False, pools=pools)
+        assert (waves is None) == (pools == 0)
+        assert waves is None or not any(k for k, _ in waves.narrow)
+        return _ops_without_metadata(
+            _compile_one_chip(one, fn, arrs, carry, mask_shape).as_text())
+
+    as_is = build()
+    exec_cache.batched_lane_fn.cache_clear()
+    W._PLAN_CACHE.clear()
+    monkeypatch.setattr(W, "narrowing_exact", lambda cfg: False)
+    assert build() == as_is
